@@ -82,7 +82,7 @@ def _committed_stale_epochs(cluster: NVMCluster) -> int:
     for be in cluster.blades.values():
         for name, area in be._log_areas.items():
             if name.endswith(".oplog"):
-                buf = bytes(be.arena[area.addr:area.addr + area.size])
+                buf = be.arena.snapshot(area.addr, area.addr + area.size)
                 total += stale_epoch_entries(buf)
     return total
 

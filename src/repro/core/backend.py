@@ -16,6 +16,10 @@ Layout of the NVM arena::
 Everything needed for recovery lives in the arena itself; ``recover()``
 rebuilds all volatile state (free lists, log-head caches) from bytes, and
 ``decode_txs`` drops torn tails by checksum, per paper §4.2/§7.5.
+
+The arena and each mirror arena live in device memory
+(:class:`~repro.core.devmem.DeviceArena`); every byte access below goes
+through that interface.
 """
 
 from __future__ import annotations
@@ -24,6 +28,9 @@ import collections
 import struct
 from typing import Deque, Dict, List, Optional, Tuple
 
+import numpy as np
+
+from .devmem import DeviceArena
 from .oplog import (
     MemLog,
     decode_oplogs,
@@ -104,7 +111,7 @@ class Mirror:
     """
 
     def __init__(self, capacity: int, cost: Optional[CostModel] = None):
-        self.arena = bytearray(capacity)
+        self.arena = DeviceArena(capacity)
         self.bytes_replicated = 0
         self.link = Link(cost or CostModel())
         self.lag_writes = 0   # replication-channel depth (0 = synchronous)
@@ -176,7 +183,7 @@ class Mirror:
             self._n_pending -= len(unit)
 
     def _apply_now(self, addr: int, data: bytes) -> None:
-        self.arena[addr : addr + len(data)] = data
+        self.arena.write_runs([(addr, data)])
         self.bytes_replicated += len(data)
 
     def sync(self) -> None:
@@ -193,12 +200,12 @@ class Mirror:
     def read(self, addr: int, size: int) -> bytes:
         if self.lag_ns > 0 and self._pending:
             self._drain()  # time-held units apply as sim time advances
-        return bytes(self.arena[addr : addr + size])
+        return self.arena.read(addr, size)
 
     def word(self, addr: int) -> int:
         if self.lag_ns > 0 and self._pending:
             self._drain()
-        return struct.unpack_from("<Q", self.arena, addr)[0]
+        return struct.unpack("<Q", self.arena.read(addr, 8))[0]
 
 
 class NVMBackend:
@@ -219,7 +226,7 @@ class NVMBackend:
         self.blade_id = blade_id
         self.num_name_slots = name_slots
         self.naming_end = name_slots * NAME_SLOT
-        self.arena = bytearray(capacity)
+        self.arena = DeviceArena(capacity)
         self.link = Link(self.cost)
         self.clock = Clock()
         self.stats = Stats()
@@ -245,6 +252,7 @@ class NVMBackend:
         self.heap_start = _align(self.bitmap_start + self.bitmap_len, block_size)
         self.n_blocks = (capacity - self.heap_start) // block_size
         self._free: List[int] = []      # recycled single blocks
+        self._free_set = set()          # the same blocks, for bitmap bytes
         self._next_fresh = 0            # bump pointer into never-used blocks
         self._names: Dict[str, int] = {}  # name -> slot index (cache of arena)
         self._log_areas: Dict[str, "LogArea"] = {}
@@ -286,10 +294,10 @@ class NVMBackend:
                     # never left the dying blade.
                     if len(data) <= 8:
                         if cut >= len(data):
-                            self.arena[addr : addr + len(data)] = data
+                            self.arena.write_runs([(addr, data)])
                         self.alive = False
                         return
-                    self.arena[addr : addr + cut] = data[:cut]
+                    self.arena.write_runs([(addr, data[:cut])])
                     self.alive = False
                     return
                 # not the targeted slot: this write goes through untouched
@@ -305,23 +313,29 @@ class NVMBackend:
                     # NOT updated: replication of this last word never left
                     # the dying blade, so the mirror stays at the previous
                     # commit point (each copy recovers consistently).
-                    self.arena[addr : addr + len(data)] = data
+                    self.arena.write_runs([(addr, data)])
                     self.alive = False
                     return
-                data = data[:cut]
-                self.arena[addr : addr + len(data)] = data
+                self.arena.write_runs([(addr, data[:cut])])
                 self.alive = False  # power loss mid-write
                 return
-        self.arena[addr : addr + len(data)] = data
+        self.arena.write_runs([(addr, data)])
         if replicate:
             for m in self.mirrors:
                 m.apply(addr, data, self._mirror_group)
         self.clock.advance(self.cost.nvm_write_ns)
 
+    def flush(self) -> None:
+        """Land this blade's staged writes, and its mirrors', on the device
+        (end of a transaction; see ``devmem``)."""
+        self.arena.flush()
+        for m in self.mirrors:
+            m.arena.flush()
+
     # ------------------------------------------------------- one-sided verbs
     def read(self, addr: int, size: int) -> bytes:
         self._check_alive()
-        return bytes(self.arena[addr : addr + size])
+        return self.arena.read(addr, size)
 
     def write(self, addr: int, data: bytes) -> None:
         self._check_alive()
@@ -329,7 +343,7 @@ class NVMBackend:
 
     def atomic_read(self, addr: int) -> int:
         self._check_alive()
-        return struct.unpack_from("<Q", self.arena, addr)[0]
+        return struct.unpack("<Q", self.arena.read(addr, 8))[0]
 
     def atomic_add(self, addr: int, delta: int) -> int:
         self._check_alive()
@@ -355,9 +369,10 @@ class NVMBackend:
         # Tombstoned slots are skipped while probing but remembered: a new
         # name reuses the first tombstone rather than growing the table.
         tomb: Optional[int] = None
+        table = self.arena.read(0, self.naming_end)
         for slot in range(self.num_name_slots):
             base = slot * NAME_SLOT
-            cur = bytes(self.arena[base : base + 32])
+            cur = table[base : base + 32]
             if cur == key:
                 self._names[name] = slot
                 return base + 32
@@ -403,15 +418,17 @@ class NVMBackend:
         self._check_alive()
         self.check_fence(epoch, fence)
         self.set_name(name, value)
+        self.flush()
 
     def has_name(self, name: str) -> bool:
         """True iff `name` already occupies a naming slot (no allocation)."""
         if name in self._names:
             return True
         key = name.encode()[:32].ljust(32, b"\x00")
+        table = self.arena.read(0, self.naming_end)
         for slot in range(self.num_name_slots):
             base = slot * NAME_SLOT
-            cur = bytes(self.arena[base : base + 32])
+            cur = table[base : base + 32]
             if cur == key:
                 self._names[name] = slot
                 return True
@@ -489,7 +506,7 @@ class NVMBackend:
             return None
         addr = self.get_name(f"{name}.blobaddr")
         length = self.get_name(f"{name}.bloblen")
-        return bytes(self.arena[addr : addr + length])
+        return self.arena.read(addr, length)
 
     # ----------------------------------------------------- block allocation
     def alloc_blocks(self, n: int = 1) -> int:
@@ -501,31 +518,42 @@ class NVMBackend:
         self._check_alive()
         if n == 1 and self._free:
             b = self._free.pop()
-            self._set_bit(b, True)
+            self._free_set.discard(b)
+            self._persist_bit(b)
             return self.heap_start + b * self.block_size
         # bump-allocate a (contiguous) run from never-used blocks
         if self._next_fresh + n > self.n_blocks:
             raise MemoryError(f"NVM blade out of blocks (need {n} contiguous)")
         lo = self._next_fresh
-        self._next_fresh += n
         for b in range(lo, lo + n):
-            self._set_bit(b, True)
+            self._next_fresh = b + 1
+            self._persist_bit(b)
         return self.heap_start + lo * self.block_size
 
     def free_blocks(self, addr: int, n: int = 1) -> None:
         self._check_alive()
         b0 = (addr - self.heap_start) // self.block_size
         for b in range(b0, b0 + n):
-            self._set_bit(b, False)
             self._free.append(b)
+            self._free_set.add(b)
+            self._persist_bit(b)
 
-    def _set_bit(self, block: int, val: bool) -> None:
-        byte = self.bitmap_start + block // 8
-        mask = 1 << (block % 8)
-        cur = self.arena[byte]
-        self.arena[byte] = (cur | mask) if val else (cur & ~mask)
+    def _persist_bit(self, block: int) -> None:
+        """Write the bitmap byte holding `block` as the allocator now sees
+        it: a block is in use iff it is below the bump pointer and not on
+        the free list.  One byte write per bit change, as a bit-by-bit
+        read-modify-write of the persistent bitmap would issue."""
+        first = block - block % 8
+        val = 0
+        for j in range(8):
+            b = first + j
+            if b < self._next_fresh and b not in self._free_set:
+                val |= 1 << j
+        addr = self.bitmap_start + block // 8
+        byte = bytes([val])
+        self.arena.write_runs([(addr, byte)])
         for m in self.mirrors:
-            m.apply(byte, bytes([self.arena[byte]]))
+            m.apply(addr, byte)
 
     # -------------------------------------------------------------- log areas
     def create_log_area(self, name: str, size_blocks: int) -> "LogArea":
@@ -588,6 +616,7 @@ class NVMBackend:
             return off
         area.head = off + len(payload)
         self.set_name(f"{area.name}.head", area.head)
+        self.flush()
         return off
 
     def _grow_area(self, area: "LogArea") -> None:
@@ -595,7 +624,7 @@ class NVMBackend:
         update the global-naming pointers (log rotation)."""
         new_blocks = 2 * (area.size // self.block_size)
         new_addr = self.alloc_blocks(new_blocks)
-        live = bytes(self.arena[area.addr + area.applied : area.addr + area.head])
+        live = self.arena.read(area.addr + area.applied, area.head - area.applied)
         new_size = new_blocks * self.block_size
         # scrub before moving the live suffix in (recycled blocks may hold
         # stale log bytes that would decode as ghost records)
@@ -617,28 +646,24 @@ class NVMBackend:
         it.  Returns the number of transactions applied.
         """
         self._check_alive()
-        buf = bytes(self.arena[area.addr + area.applied : area.addr + area.head])
-        # Columnar fast path: decode to (addr, offset, length) arrays and
-        # apply with raw slice assigns.  Only when the apply can't fault
-        # mid-stream (no armed torn write) and every mirror is synchronous —
-        # then it is byte- and clock-identical to the per-entry
-        # ``_phys_write`` loop, which remains the fault-injection path.
+        base = area.addr + area.applied
+        buf = self.arena.read(base, area.head - area.applied)
+        # Columnar fast path: decode and validate on the host, then copy the
+        # payloads from the log region to their data addresses — in the
+        # primary and every mirror — on the device.  Only when the apply
+        # can't fault mid-stream (no armed torn write) and every mirror is
+        # synchronous — then it is byte- and clock-identical to the
+        # per-entry ``_phys_write`` loop, which remains the fault-injection
+        # path.
         if self._torn_write_at is None and all(
             m.synchronous for m in self.mirrors
         ):
             with profile("log_decode"):
                 addrs, offs, lens, n_txs, consumed = decode_txs_columnar(buf)
-            nbytes = 0
             with profile("apply_phase"):
-                arena = self.arena
-                mirror_arenas = [m.arena for m in self.mirrors]
-                mv = memoryview(buf)
-                for a, o, ln in zip(addrs.tolist(), offs.tolist(), lens.tolist()):
-                    data = mv[o : o + ln]
-                    arena[a : a + ln] = data
-                    for ma in mirror_arenas:
-                        ma[a : a + ln] = data
-                    nbytes += ln
+                self.arena.copy_runs(base + offs, addrs, lens,
+                                     into=[m.arena for m in self.mirrors])
+                nbytes = int(lens.sum())
                 for m in self.mirrors:
                     m.bytes_replicated += nbytes
             self.clock.advance(self.cost.nvm_write_ns * len(addrs))
@@ -661,6 +686,7 @@ class NVMBackend:
                     self._mirror_group = None
         area.applied += consumed
         self.set_name(f"{area.name}.applied", area.applied)
+        self.flush()
         self.clock.advance(nbytes * self.cost.backend_apply_ns_per_byte)
         self.stats.tx_commits += n_txs
         return n_txs
@@ -668,6 +694,7 @@ class NVMBackend:
     # ------------------------------------------------------ crash / recovery
     def crash(self) -> None:
         """Transient power failure: volatile state is lost, the arena persists."""
+        self.flush()
         self.alive = False
 
     def fail_permanently(self) -> None:
@@ -722,9 +749,10 @@ class NVMBackend:
         # naming cache
         self._names.clear()
         names: Dict[str, int] = {}
+        table = self.arena.read(0, self.naming_end)
         for slot in range(self.num_name_slots):
             base = slot * NAME_SLOT
-            raw = bytes(self.arena[base : base + 32])
+            raw = table[base : base + 32]
             if raw == NAME_TOMBSTONE:
                 continue  # deleted slot (reusable, not a live name)
             raw = raw.rstrip(b"\x00")
@@ -732,14 +760,13 @@ class NVMBackend:
                 names[raw.decode()] = slot
         self._names = names
         # allocation state from the persistent bitmap
-        used = [
-            b
-            for b in range(self.n_blocks)
-            if (self.arena[self.bitmap_start + b // 8] >> (b % 8)) & 1
-        ]
-        self._next_fresh = (used[-1] + 1) if used else 0
-        used_set = set(used)
-        self._free = [b for b in range(self._next_fresh) if b not in used_set]
+        bits = np.unpackbits(
+            np.frombuffer(self.arena.read(self.bitmap_start, self.bitmap_len), np.uint8),
+            bitorder="little")[: self.n_blocks]
+        used = np.flatnonzero(bits)
+        self._next_fresh = int(used[-1]) + 1 if len(used) else 0
+        self._free = np.flatnonzero(bits[: self._next_fresh] == 0).tolist()
+        self._free_set = set(self._free)
         # log areas: validate tails, truncate torn bytes, replay
         areas = sorted({n.rsplit(".", 1)[0] for n in names if n.endswith(".addr")})
         self._log_areas = {}
@@ -756,7 +783,7 @@ class NVMBackend:
             else:
                 # a torn append may have landed bytes past the recorded head,
                 # or head may have been bumped for a torn tx: scan + validate.
-                buf = bytes(self.arena[addr + applied : addr + size])
+                buf = self.arena.read(addr + applied, size - applied)
                 _, consumed = decode_txs(buf)
                 area.head = applied + consumed
                 self.set_name(f"{name}.head", area.head)
@@ -779,14 +806,14 @@ class NVMBackend:
             blade_id=self.blade_id,
             name_slots=self.num_name_slots,
         )
-        fresh.arena = bytearray(self.mirrors[idx].arena)
+        fresh.arena = self.mirrors[idx].arena.clone()
         # the promoted primary's OWN mirror set must be re-seeded with the
         # full arena before it serves: replication only ships deltas, so a
         # fresh empty mirror that receives the first post-promotion seq-slot
         # write would advertise lag 0 while holding none of the data —
         # replica reads against it would return garbage
         for m in fresh.mirrors:
-            m.arena[:] = fresh.arena
+            m.arena = fresh.arena.clone()
         return fresh.reboot()
 
 
@@ -811,9 +838,7 @@ class LogArea:
         zero — avoiding a full-area rewrite on every checkpoint is a large
         wall-clock win for long runs with big log areas."""
         extent = min(self.head, self.size)
-        live = bytes(
-            self.backend.arena[self.addr + self.applied : self.addr + self.head]
-        )
+        live = self.backend.arena.read(self.addr + self.applied, self.head - self.applied)
         self.backend._phys_write(self.addr, live + b"\x00" * (extent - len(live)))
         self.head -= self.applied
         self.applied = 0
@@ -821,10 +846,10 @@ class LogArea:
         self.backend.set_name(f"{self.name}.applied", 0)
 
     def read_unapplied(self) -> bytes:
-        return bytes(self.backend.arena[self.addr + self.applied : self.addr + self.head])
+        return self.backend.arena.read(self.addr + self.applied, self.head - self.applied)
 
     def read_all(self) -> bytes:
-        return bytes(self.backend.arena[self.addr : self.addr + self.head])
+        return self.backend.arena.read(self.addr, self.head)
 
 
 def _align(x: int, a: int) -> int:

@@ -898,26 +898,26 @@ class FrontEnd:
         st.rdma_reads += len(remote)
         if tgt.is_replica:
             st.replica_reads += len(remote)
-        # hot fetch loop: read straight off the resolved arena (primary or
-        # synchronous mirror) — one aliveness check covers the whole wave,
-        # and the byte accounting rides the same pass
+        # one device gather for the whole wave off the resolved arena
+        # (primary or mirror) — one aliveness check covers the wave, and
+        # the byte accounting rides the same pass
         if tgt.mirror_idx is None:
             tgt.backend._check_alive()
             arena = tgt.backend.arena
         else:
             arena = tgt.backend.mirrors[tgt.mirror_idx].arena
+        fetched = arena.read_runs([(addr, size) for _, addr, size in remote])
         nbytes = 0
         if self.cfg.use_cache and cacheable and tgt.cache_safe:
             items = []
-            for i, addr, size in remote:
-                data = bytes(arena[addr : addr + size])
+            for (i, addr, size), data in zip(remote, fetched):
                 out[i] = data
                 items.append((addr, data))
                 nbytes += size
             self.cache.admit_many(items)
         else:
-            for i, addr, size in remote:
-                out[i] = bytes(arena[addr : addr + size])
+            for (i, _, size), data in zip(remote, fetched):
+                out[i] = data
                 nbytes += size
         st.bytes_read += nbytes
         return out
@@ -1144,6 +1144,7 @@ class FrontEnd:
             if h.wbuf:
                 self.stats.write_waves += 1
                 self.clock.advance_to(end + self.cost.rtt_ns + self.cost.nvm_write_ns)
+                self.backend.flush()
             h.wbuf.clear()
             h.pending_ops = 0
             if h.post_flush is not None:

@@ -232,7 +232,7 @@ def _stale_epoch_total(cluster: NVMCluster) -> int:
         for name, area in be._log_areas.items():
             if name.endswith(".oplog"):
                 total += stale_epoch_entries(
-                    bytes(be.arena[area.addr:area.addr + area.size]))
+                    be.arena.snapshot(area.addr, area.addr + area.size))
     return total
 
 

@@ -19,7 +19,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -107,7 +106,7 @@ def decode_attention(
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

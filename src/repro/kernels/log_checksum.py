@@ -25,11 +25,24 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
 
 MOD = 65535
 ROWS, LANES = 8, 128
 BLOCK = ROWS * LANES  # words per grid step
+
+
+def _fold_block(w, s1, s2):
+    """Fold one [ROWS, LANES] block of words (int32, < 2^16) into the
+    running (s1, s2).  The row loop is unrolled: rows are static slices,
+    which the TPU lowering accepts where a dynamic row index is refused."""
+    weights = LANES - jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    for rr in range(ROWS):
+        wrow = w[rr:rr + 1]
+        rs1 = jnp.sum(wrow)
+        rs2 = jnp.sum(weights * wrow)
+        s2 = (s2 + LANES * s1 + rs2) % MOD
+        s1 = (s1 + rs1) % MOD
+    return s1, s2
 
 
 def _kernel(w_ref, out_ref, carry_ref):
@@ -40,19 +53,7 @@ def _kernel(w_ref, out_ref, carry_ref):
         carry_ref[0] = 0
         carry_ref[1] = 0
 
-    w = w_ref[0]  # [ROWS, LANES] int32, values < 2^16
-    weights = LANES - jax.lax.broadcasted_iota(jnp.int32, (ROWS, LANES), 1)
-
-    def row(rr, carry):
-        s1, s2 = carry
-        wrow = w[rr]
-        rs1 = jnp.sum(wrow)
-        rs2 = jnp.sum(weights[rr] * wrow)
-        s2 = (s2 + LANES * s1 + rs2) % MOD
-        s1 = (s1 + rs1) % MOD
-        return (s1, s2)
-
-    s1, s2 = jax.lax.fori_loop(0, ROWS, row, (carry_ref[0], carry_ref[1]))
+    s1, s2 = _fold_block(w_ref[0], carry_ref[0], carry_ref[1])
     carry_ref[0] = s1
     carry_ref[1] = s2
 
@@ -82,7 +83,7 @@ def fletcher32(words: jax.Array, *, interpret: bool = False) -> jax.Array:
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((2,), jnp.int32),
         scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -107,19 +108,7 @@ def _wave_kernel(meta_ref, w_ref, out_ref, carry_ref):
         carry_ref[0] = 0
         carry_ref[1] = 0
 
-    w = w_ref[0]  # [ROWS, LANES] int32, values < 2^16
-    weights = LANES - jax.lax.broadcasted_iota(jnp.int32, (ROWS, LANES), 1)
-
-    def row(rr, carry):
-        s1, s2 = carry
-        wrow = w[rr]
-        rs1 = jnp.sum(wrow)
-        rs2 = jnp.sum(weights[rr] * wrow)
-        s2 = (s2 + LANES * s1 + rs2) % MOD
-        s1 = (s1 + rs1) % MOD
-        return (s1, s2)
-
-    s1, s2 = jax.lax.fori_loop(0, ROWS, row, (carry_ref[0], carry_ref[1]))
+    s1, s2 = _fold_block(w_ref[0], carry_ref[0], carry_ref[1])
     carry_ref[0] = s1
     carry_ref[1] = s2
 
@@ -163,7 +152,16 @@ def fletcher32_wave(chunks, *, interpret: bool = False) -> "np.ndarray":
         meta[b0, 0] = 1
         meta[b0 + nb - 1, 1] = seg
         b0 += nb
-    out = pl.pallas_call(
+    out = fletcher32_wave_call(meta, w, len(chunks), interpret=interpret)
+    out = np.asarray(out).astype(np.uint32)
+    return (out[:, 1] << 16) | out[:, 0]
+
+
+def fletcher32_wave_call(meta, w, n_chunks: int, *, interpret: bool = False):
+    """The wave kernel on prepared inputs: ``meta`` [blocks, 2] int32
+    segment marks and ``w`` [blocks, ROWS, LANES] int32 words.  Returns
+    [n_chunks, 2] int32 rows of (s1, s2)."""
+    return pl.pallas_call(
         _wave_kernel,
         grid=(w.shape[0],),
         in_specs=[
@@ -171,15 +169,13 @@ def fletcher32_wave(chunks, *, interpret: bool = False) -> "np.ndarray":
             pl.BlockSpec((1, ROWS, LANES), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((len(chunks), 2), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((n_chunks, 2), jnp.int32),
         scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
     )(meta, w)
-    out = np.asarray(out).astype(np.uint32)
-    return (out[:, 1] << 16) | out[:, 0]
 
 
 def fletcher32_padded_np(data: bytes) -> int:

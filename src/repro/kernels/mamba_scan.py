@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _combine(e1, e2):
     a1, b1 = e1
@@ -116,7 +114,7 @@ def mamba_scan(
             jax.ShapeDtypeStruct((B, Din, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _combine(e1, e2):
     a1, b1 = e1
@@ -99,7 +97,7 @@ def rglru_scan(
             jax.ShapeDtypeStruct((B, 1, Dm), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

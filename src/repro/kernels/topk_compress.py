@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _kernel(x_ref, vals_ref, idx_ref, res_ref, *, k: int, block: int):
     x = x_ref[...].astype(jnp.float32)  # [1, block] — kept 2D for the VPU
@@ -62,7 +60,7 @@ def topk_compress(
             jax.ShapeDtypeStruct((nb, k), jnp.int32),
             jax.ShapeDtypeStruct((nb, block), x.dtype),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -1,6 +1,5 @@
 """Launchers: production mesh, multi-pod dry-run, train/serve drivers."""
 
-from .. import jax_compat  # noqa: F401  (installs jax.set_mesh/shard_map shims)
 from .mesh import make_production_mesh, rules_for
 
 __all__ = ["make_production_mesh", "rules_for"]

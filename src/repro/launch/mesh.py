@@ -22,7 +22,7 @@ from __future__ import annotations
 from typing import Dict, Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from ..models.config import ModelConfig
 from ..models.params import sharding_rules
@@ -31,7 +31,7 @@ from ..models.params import sharding_rules
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def rules_for(cfg: ModelConfig, mesh: Mesh, *, kind: str = "train") -> Dict:

@@ -1,6 +1,5 @@
 """Model zoo: one composable DecoderLM covering the ten assigned archs."""
 
-from .. import jax_compat  # noqa: F401  (installs jax.set_mesh/shard_map shims)
 from .config import ModelConfig, MoEConfig, SSMConfig, reduce_for_smoke
 from .model import DecoderLM
 from .params import (

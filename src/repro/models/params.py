@@ -126,28 +126,19 @@ def param_count(specs: Pytree) -> int:
 
 
 def constrain(x: jax.Array, rules: Dict[str, Any], *axes: Optional[str]) -> jax.Array:
-    """with_sharding_constraint by logical activation axes (no-op outside jit
-    mesh contexts)."""
-    try:
-        spec = logical_to_spec(tuple(axes), rules)
-        fixed = []
-        mesh = None
-        try:
-            from jax.sharding import get_abstract_mesh  # jax >= 0.4.35
-
-            mesh = get_abstract_mesh()
-        except Exception:
-            mesh = None
-        for dim, part in zip(x.shape, spec + (None,) * (len(x.shape) - len(spec))):
-            if part is None:
-                fixed.append(None)
-                continue
-            if mesh is not None and mesh.shape:
-                axs = (part,) if isinstance(part, str) else tuple(part)
-                size = math.prod(mesh.shape.get(a, 1) for a in axs)
-                fixed.append(part if size and dim % size == 0 else None)
-            else:
-                fixed.append(part)
-        return jax.lax.with_sharding_constraint(x, P(*fixed))
-    except Exception:
+    """with_sharding_constraint by logical activation axes (no-op outside a
+    ``jax.set_mesh`` context).  A dimension the mesh axes do not divide is
+    left unconstrained."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
+    spec = logical_to_spec(tuple(axes), rules)
+    fixed = []
+    for dim, part in zip(x.shape, spec + (None,) * (len(x.shape) - len(spec))):
+        if part is None:
+            fixed.append(None)
+            continue
+        axs = (part,) if isinstance(part, str) else tuple(part)
+        size = math.prod(mesh.shape.get(a, 1) for a in axs)
+        fixed.append(part if dim % size == 0 else None)
+    return jax.lax.with_sharding_constraint(x, P(*fixed))

@@ -1,4 +1,3 @@
-from .. import jax_compat  # noqa: F401  (installs jax.set_mesh/shard_map shims)
 from .optimizer import OptConfig, apply_opt, init_opt_state
 from .train_step import TrainConfig, init_train_state, make_train_step
 from .trainer import StragglerWatchdog, Trainer, TrainerConfig

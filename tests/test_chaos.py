@@ -242,8 +242,8 @@ def test_watermark_tear_is_persist_atomic_either_way():
         _put_through_power_loss(be, ht, 99, 99)
         # inspect the persisted arena bytes directly: the blade is down
         got = int.from_bytes(
-            be.arena[be.name_slot_addr("h.seq"):
-                     be.name_slot_addr("h.seq") + 8], "little")
+            be.arena.snapshot(be.name_slot_addr("h.seq"),
+                              be.name_slot_addr("h.seq") + 8), "little")
         if keep >= 8:
             assert got > old, f"keep={keep}: watermark should have landed"
         else:
@@ -257,7 +257,7 @@ def test_untargeted_tear_still_cuts_mid_entry():
     be.schedule_torn_write(5)
     be.write(be.heap_start, b"\xaa" * 16)
     assert not be.alive
-    assert bytes(be.arena[be.heap_start:be.heap_start + 16]) == \
+    assert be.arena.snapshot(be.heap_start, be.heap_start + 16) == \
         b"\xaa" * 5 + b"\x00" * 11
 
 
@@ -329,7 +329,7 @@ def test_lagging_mirror_bytes_stay_out_of_cache_under_spike():
     assert [ht.get(k) for k in range(20)] == [k + 500 for k in range(20)]
     be.mirrors[0].set_lag(0)  # spike ends: queued writes drain
     be.mirrors[0].sync()
-    assert bytes(be.mirrors[0].arena) == bytes(be.arena)
+    assert be.mirrors[0].arena.snapshot() == be.arena.snapshot()
 
 
 # ------------------------------------------- write-lease fencing chaos
@@ -393,7 +393,7 @@ def test_mirror_lag_ns_holds_bytes_until_sim_time():
     t0 = be.clock.now
     be.write(addr, b"\xab" * 16)
     assert not m.synchronous
-    assert bytes(m.arena[addr:addr + 16]) == b"\x00" * 16  # held by time
+    assert m.arena.snapshot(addr, addr + 16) == b"\x00" * 16  # held by time
     assert m.read(addr, 16) == b"\x00" * 16                # still too young
     be.clock.advance_to(t0 + 1_001.0)
     assert m.read(addr, 16) == b"\xab" * 16  # read drained the held unit
@@ -410,7 +410,7 @@ def test_mirror_lag_ns_holds_bytes_until_sim_time():
     m.lag_writes = 0
     m.set_lag_ns(0)
     m.sync()
-    assert bytes(m.arena) == bytes(be.arena)
+    assert m.arena.snapshot() == be.arena.snapshot()
     assert m.synchronous
 
 

@@ -27,7 +27,7 @@ def test_mirror_arena_byte_exact_after_clean_workload():
             ht.delete(k)
     fe.drain(ht.h)
     for m in be.mirrors:
-        assert bytes(m.arena) == bytes(be.arena)
+        assert m.arena.snapshot() == be.arena.snapshot()
         assert m.bytes_replicated > 0
 
 
@@ -38,22 +38,22 @@ def test_torn_write_never_reaches_the_mirror():
     for k in range(120):
         ht.put(k, k * 7)
     fe.drain(ht.h)
-    assert bytes(be.mirrors[0].arena) == bytes(be.arena)
+    assert be.mirrors[0].arena.snapshot() == be.arena.snapshot()
 
     # stage ops client-side (large groups: no log flushes; only slab-alloc
     # RPCs reach the blade), then let the flush tear mid-write
     for k in range(120, 140):
         ht.put(k, 1)
-    snapshot = bytes(be.arena)
-    assert bytes(be.mirrors[0].arena) == snapshot
+    snapshot = be.arena.snapshot()
+    assert be.mirrors[0].arena.snapshot() == snapshot
     be.schedule_torn_write(17)
     with pytest.raises(CrashError):
         fe.drain(ht.h)
         fe.drain(ht.h)  # second drain hits the dead blade if first "worked"
     # the partial write mutated the primary ...
-    assert bytes(be.arena) != snapshot
+    assert be.arena.snapshot() != snapshot
     # ... but the mirror still matches the last commit point byte for byte
-    assert bytes(be.mirrors[0].arena) == snapshot
+    assert be.mirrors[0].arena.snapshot() == snapshot
 
 
 def test_promotion_equals_reboot_after_torn_write_crash():

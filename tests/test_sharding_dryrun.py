@@ -26,14 +26,15 @@ def test_mini_mesh_train_lower_compile_and_collectives():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, dataclasses, json
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke_config
         from repro.models import DecoderLM, abstract_params, make_shardings
         from repro.launch.mesh import rules_for
         from repro.launch.analysis import parse_collectives
         from repro.training import TrainConfig, make_train_step, init_train_state
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_smoke_config("llama3.2-3b", fsdp=True, scan_layers=False)
         rules = rules_for(cfg, mesh, kind="train")
         model = DecoderLM(cfg)
@@ -62,13 +63,15 @@ def test_mini_mesh_moe_ep_a2a_runs():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, dataclasses, numpy as np
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.models import DecoderLM
         from repro.models.moe import moe_apply, moe_specs
         from repro.models.params import init_params
         from repro.launch.analysis import parse_collectives
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_smoke_config("kimi-k2-1t-a32b", dtype="float32")
         # 8 experts over model=4: EP path; generous capacity for exactness
         m = dataclasses.replace(cfg.moe, impl="ep_a2a", capacity_factor=8.0)
@@ -98,11 +101,13 @@ def test_mini_mesh_decode_and_seq_parallel_attention():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, dataclasses
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.models import DecoderLM
         from repro.launch.mesh import rules_for
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         # 3 heads: NOT divisible by model=4 -> sequence-parallel rules
         cfg = get_smoke_config("llama3.2-3b", n_heads=3, n_kv_heads=3, head_dim=32,
                                d_model=96, d_ff=128, dtype="float32")

@@ -86,7 +86,7 @@ def test_vectorized_apply_byte_identical_to_serial(pairs):
         t_b.put_many(pairs)
         fe_b.drain(t_b.h)
 
-        assert bytes(be_s.arena) == bytes(be_b.arena), cls.__name__
+        assert be_s.arena.snapshot() == be_b.arena.snapshot(), cls.__name__
         assert fe_b.clock.now <= fe_s.clock.now, cls.__name__
 
 
