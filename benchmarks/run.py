@@ -30,10 +30,11 @@ def _write_record(path: str, rows: list, prefix: str, preload: int,
         "wall_clock_seconds": round(wall_s, 1),
     }
     if phases:
+        spans = {k: v for k, v in phases.items() if "seconds" in v}
         meta["profile_phase_seconds"] = {
-            k: round(v["seconds"], 3) for k, v in phases.items()
+            k: round(v["seconds"], 3) for k, v in spans.items()
         }
-        meta["profile_phase_calls"] = {k: v["calls"] for k, v in phases.items()}
+        meta["profile_phase_calls"] = {k: v["calls"] for k, v in spans.items()}
     record = rows + [meta]
     with open(path, "w") as f:
         json.dump(record, f, indent=2)

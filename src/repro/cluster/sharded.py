@@ -44,6 +44,7 @@ from ..core.locks import WriterPreferredLock
 from ..core.structures import RemoteBPTree, RemoteHashTable
 from ..core.structures.mv_bpt import RemoteMVBPTree
 from .. import obs
+from ..obs.profile import profile
 from .directory import scope_of
 from .router import ClusterFrontEnd
 
@@ -218,10 +219,12 @@ class ShardedStructure:
         """Multi-writer freshness check on the cached-shard fast path:
         another front-end may have committed past our view of the shard's
         op stream (only possible after our write lease moved — while we
-        hold it, nobody else can commit, and this is a free no-op).  Roll
+        hold it, nobody else can commit, and the check finds nothing).  Roll
         the committed-tail view forward and drop caches whose pages the
-        other writer's commits may shadow."""
-        durable = obj.fe.backend.get_name(f"{obj.name}.seq")
+        other writer's commits may shadow.  The probe reads the committed
+        watermark off the blade: one device read, on every visit."""
+        with profile("shard.probe"):
+            durable = obj.fe.backend.get_name(f"{obj.name}.seq")
         if durable > obj.h.seq:
             obj.h.seq = durable
             obj.fe.cache.clear()
@@ -619,6 +622,10 @@ class ShardedStructure:
         single combined oplog+memlog posted write.  Every written key is
         pinned at the batch's closing op-seq (conservative: the whole batch
         must reach the mirrors before any of its keys reads from one)."""
+        with profile("store.put_many"):
+            self._put_many(pairs)
+
+    def _put_many(self, pairs: List[Tuple[int, int]]) -> None:
         if self._result_cache is not None:
             for k, _ in pairs:
                 self._rc_invalidate(k)
@@ -648,6 +655,10 @@ class ShardedStructure:
         the primary.  With a result cache, unpinned keys probe it first —
         hits are served locally at DRAM cost, only misses fan out (and
         cache-safe miss results are admitted on the way back)."""
+        with profile("store.get_many"):
+            return self._get_many(keys)
+
+    def _get_many(self, keys: List[int]) -> List[Optional[int]]:
         rc = self._result_cache
         out: List[Optional[int]] = [None] * len(keys)
         if rc is None:
@@ -855,19 +866,20 @@ class ShardedBPTree(ShardedStructure):
     def range_scan(self, lo: int, hi: int) -> List[Tuple[int, int]]:
         """All (key, value) with lo <= key <= hi, globally sorted: per-shard
         leaf-chain scans merged with a k-way heap merge."""
-        streams: List[List[Tuple[int, int]]] = []
-        for shard in range(self.cfe.directory.n_shards):
-            part = self._on_shard(
-                shard,
-                lambda t, s=shard: self._serve_scan(
-                    s, t, lambda o: o.range_items(lo, hi)
-                ),
-                create_if_missing=False,
-                default=[],
-            )
-            if part:
-                streams.append(part)
-        return list(heapq.merge(*streams))
+        with profile("store.range_scan"):
+            streams: List[List[Tuple[int, int]]] = []
+            for shard in range(self.cfe.directory.n_shards):
+                part = self._on_shard(
+                    shard,
+                    lambda t, s=shard: self._serve_scan(
+                        s, t, lambda o: o.range_items(lo, hi)
+                    ),
+                    create_if_missing=False,
+                    default=[],
+                )
+                if part:
+                    streams.append(part)
+            return list(heapq.merge(*streams))
 
     def items(self) -> List[Tuple[int, int]]:
         streams: List[List[Tuple[int, int]]] = []
@@ -926,19 +938,20 @@ class ShardedMVBPTree(ShardedStructure):
             return self._on_shard(shard, run, create_if_missing=False)
 
     def range_scan(self, lo: int, hi: int) -> List[Tuple[int, int]]:
-        streams: List[List[Tuple[int, int]]] = []
-        for shard in range(self.cfe.directory.n_shards):
-            part = self._on_shard(
-                shard,
-                lambda t, s=shard: self._serve_scan(
-                    s, t, lambda o: o.range_items(lo, hi)
-                ),
-                create_if_missing=False,
-                default=[],
-            )
-            if part:
-                streams.append(part)
-        return list(heapq.merge(*streams))
+        with profile("store.range_scan"):
+            streams: List[List[Tuple[int, int]]] = []
+            for shard in range(self.cfe.directory.n_shards):
+                part = self._on_shard(
+                    shard,
+                    lambda t, s=shard: self._serve_scan(
+                        s, t, lambda o: o.range_items(lo, hi)
+                    ),
+                    create_if_missing=False,
+                    default=[],
+                )
+                if part:
+                    streams.append(part)
+            return list(heapq.merge(*streams))
 
     def items(self) -> List[Tuple[int, int]]:
         return self.range_scan(-(1 << 63), (1 << 63) - 1)

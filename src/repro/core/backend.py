@@ -40,7 +40,7 @@ from .oplog import (
     encode_tx,
 )
 from .sim import Clock, CostModel, Link, Stats
-from ..obs.profile import profile
+from ..obs.profile import count, profile
 
 NAME_SLOT = 40  # 32B name + 8B value
 NUM_NAME_SLOTS = 512
@@ -404,7 +404,9 @@ class NVMBackend:
         return True
 
     def get_name(self, name: str) -> int:
-        return self.atomic_read(self.name_slot_addr(name))
+        addr = self.name_slot_addr(name)
+        count("reads.name_probe")
+        return self.atomic_read(addr)
 
     def set_name(self, name: str, value: int) -> None:
         self._phys_write(self.name_slot_addr(name), struct.pack("<Q", value))
@@ -647,6 +649,7 @@ class NVMBackend:
         """
         self._check_alive()
         base = area.addr + area.applied
+        count("reads.apply_log")
         buf = self.arena.read(base, area.head - area.applied)
         # Columnar fast path: decode and validate on the host, then copy the
         # payloads from the log region to their data addresses — in the

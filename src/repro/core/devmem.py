@@ -22,6 +22,14 @@ byte 0, padded scatter lanes carry negative indices, which
 buckets x arena sizes x arenas per copy; ``compiled_programs`` counts them.
 Byte indices are ``int32`` (64-bit mode stays off), which bounds an arena
 to ``MAX_CAPACITY`` bytes.
+
+With ``repro.obs.profile`` enabled, each call splits its wall-clock time
+into ``arena.<read|flush|copy>.prep`` (host index building and padding) and
+``.dispatch`` (the jitted call until it returns), and a read adds
+``arena.read.wait``: waiting for the program on the device, with the rest
+of the copy to the host as its child ``arena.read.copy``.  Counter
+``arena.reads`` counts ``read_runs`` calls.  Disabled, a read does not wait
+apart from its copy.
 """
 
 from __future__ import annotations
@@ -35,6 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from ..obs.profile import count, enabled as _profiling, profile
 
 MAX_CAPACITY = 1 << 31   # int32 byte indices
 MIN_BUCKET = 64          # smallest padded wave, in bytes
@@ -123,6 +133,20 @@ def _last_wins(dst: np.ndarray, starts: np.ndarray, lens: np.ndarray,
     return (dst,) + cols
 
 
+def _to_host(got: jax.Array) -> np.ndarray:
+    """The result of a read program on the host; while profiling, the wait
+    for the program and the rest of the copy are timed apart.  The copy is
+    queued before the wait, as ``np.asarray`` alone queues it: started only
+    after the wait, it would add a round trip to every read."""
+    if not _profiling():
+        return np.asarray(got)
+    with profile("arena.read.wait"):
+        got.copy_to_host_async()
+        got.block_until_ready()
+        with profile("arena.read.copy"):
+            return np.asarray(got)
+
+
 def _pad(idx: np.ndarray, fill: str) -> np.ndarray:
     pad = _bucket(len(idx)) - len(idx)
     if fill == "drop":
@@ -174,41 +198,51 @@ class DeviceArena:
         if not self._staged:
             return
         staged, self._staged = self._staged, []
-        if len(staged) == 1:
-            addr, data = staged[0]
-            dst = np.arange(addr, addr + len(data), dtype=np.int32)
-            vals = np.frombuffer(data, np.uint8)
-        else:  # runs were checked when staged
-            starts = np.fromiter((a for a, _ in staged), np.int64, len(staged))
-            lens = np.fromiter((len(d) for _, d in staged), np.int64, len(staged))
-            vals = np.frombuffer(b"".join(d for _, d in staged), np.uint8)
-            dst, vals = _last_wins(_run_indices(starts, lens), starts, lens, vals)
+        with profile("arena.flush.prep"):
+            if len(staged) == 1:
+                addr, data = staged[0]
+                dst = np.arange(addr, addr + len(data), dtype=np.int32)
+                vals = np.frombuffer(data, np.uint8)
+            else:  # runs were checked when staged
+                starts = np.fromiter((a for a, _ in staged), np.int64, len(staged))
+                lens = np.fromiter((len(d) for _, d in staged), np.int64, len(staged))
+                vals = np.frombuffer(b"".join(d for _, d in staged), np.uint8)
+                dst, vals = _last_wins(_run_indices(starts, lens), starts, lens, vals)
         for lo in range(0, len(dst), MAX_BUCKET):
-            d = dst[lo:lo + MAX_BUCKET]
-            v = np.zeros(_bucket(len(d)), np.uint8)
-            v[:len(d)] = vals[lo:lo + MAX_BUCKET]
-            self._array = scatter_program(self._array, _pad(d, "drop"), v)
+            with profile("arena.flush.prep"):
+                d = dst[lo:lo + MAX_BUCKET]
+                v = np.zeros(_bucket(len(d)), np.uint8)
+                v[:len(d)] = vals[lo:lo + MAX_BUCKET]
+                d = _pad(d, "drop")
+            with profile("arena.flush.dispatch"):
+                self._array = scatter_program(self._array, d, v)
 
     # -------------------------------------------------------------- reads
     def read_runs(self, runs: Sequence[Tuple[int, int]]) -> List[bytes]:
         """The bytes of each ``(addr, n)`` run: one gather and one transfer
         per wave (chunked past MAX_BUCKET)."""
         self.flush()
+        count("arena.reads")
         if len(runs) == 1:
             addr, n = runs[0]
             self._check(addr, n)
             size = _bucket(n)
             if size <= min(self.capacity, MAX_BUCKET):
                 lo = min(addr, self.capacity - size)  # dynamic_slice would clamp
-                got = slice_program(self._array, np.int32(lo), size)
-                return [np.asarray(got)[addr - lo:addr - lo + n].tobytes()]
-        starts, lens = self._runs(runs)
-        idx = _run_indices(starts, lens)
+                with profile("arena.read.dispatch"):
+                    got = slice_program(self._array, np.int32(lo), size)
+                return [_to_host(got)[addr - lo:addr - lo + n].tobytes()]
+        with profile("arena.read.prep"):
+            starts, lens = self._runs(runs)
+            idx = _run_indices(starts, lens)
         parts = []
         for lo in range(0, len(idx), MAX_BUCKET):
-            chunk = idx[lo:lo + MAX_BUCKET]
-            got = gather_program(self._array, _pad(chunk, "zero"))
-            parts.append(np.asarray(got)[:len(chunk)].tobytes())
+            with profile("arena.read.prep"):
+                chunk = idx[lo:lo + MAX_BUCKET]
+                padded = _pad(chunk, "zero")
+            with profile("arena.read.dispatch"):
+                got = gather_program(self._array, padded)
+            parts.append(_to_host(got)[:len(chunk)].tobytes())
         buf = b"".join(parts)
         out = []
         o = 0
@@ -234,20 +268,22 @@ class DeviceArena:
         arenas = [self, *into]
         for a in arenas:
             a.flush()
-        src = np.asarray(src, np.int64)
-        dst = np.asarray(dst, np.int64)
-        lens = np.asarray(lens, np.int64)
-        if not len(lens):
-            return
-        self._runs(np.stack([src, lens], 1))
-        self._runs(np.stack([dst, lens], 1))
-        d_idx, s_idx = _last_wins(_run_indices(dst, lens), dst, lens,
-                                  _run_indices(src, lens))
+        with profile("arena.copy.prep"):
+            src = np.asarray(src, np.int64)
+            dst = np.asarray(dst, np.int64)
+            lens = np.asarray(lens, np.int64)
+            if not len(lens):
+                return
+            self._runs(np.stack([src, lens], 1))
+            self._runs(np.stack([dst, lens], 1))
+            d_idx, s_idx = _last_wins(_run_indices(dst, lens), dst, lens,
+                                      _run_indices(src, lens))
         for lo in range(0, len(d_idx), MAX_BUCKET):
-            d = d_idx[lo:lo + MAX_BUCKET]
-            s = s_idx[lo:lo + MAX_BUCKET]
-            new = copy_program(tuple(a._array for a in arenas),
-                               _pad(s, "zero"), _pad(d, "drop"))
+            with profile("arena.copy.prep"):
+                d = _pad(d_idx[lo:lo + MAX_BUCKET], "drop")
+                s = _pad(s_idx[lo:lo + MAX_BUCKET], "zero")
+            with profile("arena.copy.dispatch"):
+                new = copy_program(tuple(a._array for a in arenas), s, d)
             for a, arr in zip(arenas, new):
                 a._array = arr
 

@@ -97,7 +97,7 @@ from .oplog import (MemLog, OpLog, committed_tail, encode_epoch_mark,
 from .sim import Clock, CostModel, Stats
 from .. import obs
 from ..obs.hist import LatencyHistogram
-from ..obs.profile import profile
+from ..obs.profile import count, profile
 
 
 class LinkTimeout(CrashError):
@@ -816,6 +816,7 @@ class FrontEnd:
             return bytes(staged[:size])
         if self.cfg.symmetric:
             self.clock.advance(self.cost.nvm_read_ns)
+            count("reads.serial")
             return self.backend.read(addr, size)
         if self.cfg.use_cache and cacheable:
             page = self.cache.get(addr)
@@ -825,6 +826,7 @@ class FrontEnd:
                 return bytes(page[:size])
             self.stats.cache_misses += 1
         tgt = self._read_target(h)
+        count("reads.serial")
         data = tgt.fetch(addr, size)
         self.stats.rdma_reads += 1
         self.stats.bytes_read += size
@@ -848,79 +850,81 @@ class FrontEnd:
         collapse to a few messages).  The whole wave goes to ONE resolved
         ``target`` endpoint (primary or mirror) and charges that blade's
         link."""
-        tgt = target or ReadTarget(self.backend)
-        tr = self.trace
-        t0 = self.clock.now
-        cost = self.cost
-        with profile("wave_build"):
-            runs = combine_runs([(a, s) for _, a, s in remote])
-            width = self.waves.width
+        with profile("fe.read_wave"):
+            tgt = target or ReadTarget(self.backend)
+            tr = self.trace
+            t0 = self.clock.now
+            cost = self.cost
+            with profile("wave_build"):
+                runs = combine_runs([(a, s) for _, a, s in remote])
+                width = self.waves.width
 
-            def charge():
-                if len(runs) > 1:
-                    # vectorized WQE stream: every run's post gap + link
-                    # transfer in one epoch-chunked pass (transfer_many)
-                    wqe_ns = cost.doorbell_wqe_ns
-                    issue_ns = cost.issue_ns
-                    gaps = [
-                        issue_ns if i % width == 0 else wqe_ns
-                        for i in range(len(runs))
-                    ]
-                    ends = tgt.link.transfer_many(
-                        self.clock.now, gaps, [nb for _, nb in runs]
-                    )
-                    start = float(ends[-1])
+                def charge():
+                    if len(runs) > 1:
+                        # vectorized WQE stream: every run's post gap + link
+                        # transfer in one epoch-chunked pass (transfer_many)
+                        wqe_ns = cost.doorbell_wqe_ns
+                        issue_ns = cost.issue_ns
+                        gaps = [
+                            issue_ns if i % width == 0 else wqe_ns
+                            for i in range(len(runs))
+                        ]
+                        ends = tgt.link.transfer_many(
+                            self.clock.now, gaps, [nb for _, nb in runs]
+                        )
+                        start = float(ends[-1])
+                    else:
+                        start = self.clock.now
+                        for i, (_, nbytes) in enumerate(runs):
+                            start += cost.issue_ns if i % width == 0 else cost.doorbell_wqe_ns
+                            start = tgt.link.transfer(start, nbytes)
+                    self.clock.advance_to(start + cost.rtt_ns + cost.nvm_read_ns)
+
+                if tgt.link.fault is None and tgt.link.breaker is None:
+                    charge()
                 else:
-                    start = self.clock.now
-                    for i, (_, nbytes) in enumerate(runs):
-                        start += cost.issue_ns if i % width == 0 else cost.doorbell_wqe_ns
-                        start = tgt.link.transfer(start, nbytes)
-                self.clock.advance_to(start + cost.rtt_ns + cost.nvm_read_ns)
-
-            if tgt.link.fault is None and tgt.link.breaker is None:
-                charge()
+                    # read-wave deadline: a timed-out wave re-charges whole (the
+                    # doorbell is re-rung; data is fetched only after success)
+                    self._with_deadline(tgt.link, charge)
+            if tr is not None:
+                tr.span(self._tk, "read_wave", t0, self.clock.now,
+                        {"wqes": len(runs), "items": len(remote),
+                         "bytes": sum(n for _, n in runs), "width": width,
+                         "replica": tgt.is_replica})
+                if self.cfg.use_cache:
+                    c = self.cache
+                    tr.counter(self._tk, "cache", self.clock.now,
+                               {"hits": c.hits, "misses": c.misses,
+                                "evictions": c.evictions})
+            out: Dict[int, bytes] = {}
+            st = self.stats
+            st.rdma_reads += len(remote)
+            if tgt.is_replica:
+                st.replica_reads += len(remote)
+            # one device gather for the whole wave off the resolved arena
+            # (primary or mirror) — one aliveness check covers the wave, and
+            # the byte accounting rides the same pass
+            if tgt.mirror_idx is None:
+                tgt.backend._check_alive()
+                arena = tgt.backend.arena
             else:
-                # read-wave deadline: a timed-out wave re-charges whole (the
-                # doorbell is re-rung; data is fetched only after success)
-                self._with_deadline(tgt.link, charge)
-        if tr is not None:
-            tr.span(self._tk, "read_wave", t0, self.clock.now,
-                    {"wqes": len(runs), "items": len(remote),
-                     "bytes": sum(n for _, n in runs), "width": width,
-                     "replica": tgt.is_replica})
-            if self.cfg.use_cache:
-                c = self.cache
-                tr.counter(self._tk, "cache", self.clock.now,
-                           {"hits": c.hits, "misses": c.misses,
-                            "evictions": c.evictions})
-        out: Dict[int, bytes] = {}
-        st = self.stats
-        st.rdma_reads += len(remote)
-        if tgt.is_replica:
-            st.replica_reads += len(remote)
-        # one device gather for the whole wave off the resolved arena
-        # (primary or mirror) — one aliveness check covers the wave, and
-        # the byte accounting rides the same pass
-        if tgt.mirror_idx is None:
-            tgt.backend._check_alive()
-            arena = tgt.backend.arena
-        else:
-            arena = tgt.backend.mirrors[tgt.mirror_idx].arena
-        fetched = arena.read_runs([(addr, size) for _, addr, size in remote])
-        nbytes = 0
-        if self.cfg.use_cache and cacheable and tgt.cache_safe:
-            items = []
-            for (i, addr, size), data in zip(remote, fetched):
-                out[i] = data
-                items.append((addr, data))
-                nbytes += size
-            self.cache.admit_many(items)
-        else:
-            for (i, _, size), data in zip(remote, fetched):
-                out[i] = data
-                nbytes += size
-        st.bytes_read += nbytes
-        return out
+                arena = tgt.backend.mirrors[tgt.mirror_idx].arena
+            count("reads.wave")
+            fetched = arena.read_runs([(addr, size) for _, addr, size in remote])
+            nbytes = 0
+            if self.cfg.use_cache and cacheable and tgt.cache_safe:
+                items = []
+                for (i, addr, size), data in zip(remote, fetched):
+                    out[i] = data
+                    items.append((addr, data))
+                    nbytes += size
+                self.cache.admit_many(items)
+            else:
+                for (i, _, size), data in zip(remote, fetched):
+                    out[i] = data
+                    nbytes += size
+            st.bytes_read += nbytes
+            return out
 
     def read_many(self, h: StructHandle, reqs: List[Tuple[int, int]], *, cacheable: bool = True) -> List[bytes]:
         """Doorbell-batched independent reads (vector ops): one issue + one
@@ -1253,74 +1257,76 @@ class FrontEnd:
         dirty = [h for h in handles if h.wbuf or h.pending_ops or h.oplog_staged]
         if not dirty:
             return
-        total = 0
-        # op-log bytes first, every handle (durability ordering).  A fenced
-        # handle whose lease was stolen raises StaleWriterError here: its
-        # staged window is discarded (unacked, so it simply vanishes) and
-        # the error propagates — handles already flushed in this loop were
-        # committed by their own watermark write and stay committed, the
-        # same per-handle all-or-none story as a torn flush.
-        for h in dirty:
-            if not h.oplog_staged:
-                continue
-            oplog_payload = b"".join(h.oplog_staged)
-            epoch, fence = self._fence_of(h)
-            try:
-                self.backend.tx_append(h.oplog_area, oplog_payload, epoch, fence)
-                self.backend.set_name_fenced(f"{h.name}.seq", h.seq, epoch, fence)
-            except StaleWriterError:
-                self.discard_staged(h)
-                raise
-            h.oplog_staged.clear()
-            h.oplog_staged_ops = 0
-            total += len(oplog_payload)
-            if h.wbuf or h.pending_ops:
-                self.stats.combined_flushes += 1
-        flushed: List[StructHandle] = []
-        for h in dirty:
-            if not h.wbuf and h.pending_ops == 0:
-                continue
-            # the opsn watermark trails the data writes it covers: the tx
-            # still applies all-or-none on recovery (intra-tx order is free
-            # there), but mirrors apply the stream write-by-write, so a
-            # mirror's opsn copy must never advance past data it is missing
-            # — replica reads gate on it (NVMBackend.replica_whole_seq)
-            entries = [MemLog(a, d) for a, d in h.wbuf.items()]
-            entries.append(MemLog(self.backend.name_slot_addr(h.opsn_name),
-                                  struct.pack("<Q", h.seq)))
-            payload = encode_tx(entries)
-            epoch, fence = self._fence_of(h)
-            try:
-                self.backend.tx_append(h.txlog_area, payload, epoch, fence)
-            except StaleWriterError:
-                self.discard_staged(h)
-                raise
-            total += len(payload)
-            self.stats.memlogs_flushed += len(h.wbuf)
-            h.wbuf.clear()
-            h.pending_ops = 0
-            flushed.append(h)
-        self.stats.rdma_writes += 1
-        self.stats.bytes_written += total
-        if sync:
-            self._round(total, nvm_write=True)
-        else:
-            self._pipelined_write(total)
-        for h in flushed:
-            # the blade applies committed logs off the front-end's critical path
-            self.backend.tx_apply(h.txlog_area)
-            # op logs <= h.seq are now reflected in the data area: advance LPN
-            h.oplog_area.applied = h.oplog_area.head
-            if h.oplog_area.head > h.oplog_area.size // 2:
-                h.oplog_area.compact()
-            if h.txlog_area.applied > h.txlog_area.size // 2:
-                h.txlog_area.compact()
-        for h in flushed:
-            if h.post_flush is not None and not h._in_preflush:
-                h.post_flush()
-        if tr is not None:
-            tr.span(self._tk, "flush", t0, self.clock.now,
-                    {"handles": len(dirty), "bytes": total, "sync": sync})
+        count("commits")
+        with profile("fe.group_commit"):
+            total = 0
+            # op-log bytes first, every handle (durability ordering).  A fenced
+            # handle whose lease was stolen raises StaleWriterError here: its
+            # staged window is discarded (unacked, so it simply vanishes) and
+            # the error propagates — handles already flushed in this loop were
+            # committed by their own watermark write and stay committed, the
+            # same per-handle all-or-none story as a torn flush.
+            for h in dirty:
+                if not h.oplog_staged:
+                    continue
+                oplog_payload = b"".join(h.oplog_staged)
+                epoch, fence = self._fence_of(h)
+                try:
+                    self.backend.tx_append(h.oplog_area, oplog_payload, epoch, fence)
+                    self.backend.set_name_fenced(f"{h.name}.seq", h.seq, epoch, fence)
+                except StaleWriterError:
+                    self.discard_staged(h)
+                    raise
+                h.oplog_staged.clear()
+                h.oplog_staged_ops = 0
+                total += len(oplog_payload)
+                if h.wbuf or h.pending_ops:
+                    self.stats.combined_flushes += 1
+            flushed: List[StructHandle] = []
+            for h in dirty:
+                if not h.wbuf and h.pending_ops == 0:
+                    continue
+                # the opsn watermark trails the data writes it covers: the tx
+                # still applies all-or-none on recovery (intra-tx order is free
+                # there), but mirrors apply the stream write-by-write, so a
+                # mirror's opsn copy must never advance past data it is missing
+                # — replica reads gate on it (NVMBackend.replica_whole_seq)
+                entries = [MemLog(a, d) for a, d in h.wbuf.items()]
+                entries.append(MemLog(self.backend.name_slot_addr(h.opsn_name),
+                                      struct.pack("<Q", h.seq)))
+                payload = encode_tx(entries)
+                epoch, fence = self._fence_of(h)
+                try:
+                    self.backend.tx_append(h.txlog_area, payload, epoch, fence)
+                except StaleWriterError:
+                    self.discard_staged(h)
+                    raise
+                total += len(payload)
+                self.stats.memlogs_flushed += len(h.wbuf)
+                h.wbuf.clear()
+                h.pending_ops = 0
+                flushed.append(h)
+            self.stats.rdma_writes += 1
+            self.stats.bytes_written += total
+            if sync:
+                self._round(total, nvm_write=True)
+            else:
+                self._pipelined_write(total)
+            for h in flushed:
+                # the blade applies committed logs off the front-end's critical path
+                self.backend.tx_apply(h.txlog_area)
+                # op logs <= h.seq are now reflected in the data area: advance LPN
+                h.oplog_area.applied = h.oplog_area.head
+                if h.oplog_area.head > h.oplog_area.size // 2:
+                    h.oplog_area.compact()
+                if h.txlog_area.applied > h.txlog_area.size // 2:
+                    h.txlog_area.compact()
+            for h in flushed:
+                if h.post_flush is not None and not h._in_preflush:
+                    h.post_flush()
+            if tr is not None:
+                tr.span(self._tk, "flush", t0, self.clock.now,
+                        {"handles": len(dirty), "bytes": total, "sync": sync})
 
     def drain(self, h: StructHandle) -> None:
         """Flush everything (end of benchmark / clean shutdown)."""

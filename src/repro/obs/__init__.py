@@ -249,9 +249,17 @@ class ObsSession:
                                "(0 closed, 1 open)",
                           cluster=c, blade=str(bid))
         for site, d in _profile.snapshot().items():
+            if "count" in d:
+                reg.counter("profile_count", d["count"],
+                            help="obs.profile counters (device reads by cause, "
+                                 "commits)", site=site)
+                continue
             reg.counter("profile_seconds", d["seconds"],
                         help="wall-clock seconds inside obs.profile regions",
                         site=site)
+            reg.counter("profile_self_seconds", d["self_seconds"],
+                        help="wall-clock seconds inside obs.profile regions, "
+                             "less their nested regions", site=site)
             reg.counter("profile_calls", d["calls"], site=site)
         return reg
 
