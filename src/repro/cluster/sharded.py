@@ -745,7 +745,10 @@ class ShardedHashTable(ShardedStructure):
                  result_cache: Optional[int] = None):
         super().__init__(cfe, name, read_policy=read_policy,
                          result_cache=result_cache)
-        # n_buckets is the logical total; each shard gets its slice
+        # n_buckets is the logical total; each shard gets its slice.  The
+        # router takes a key's shard from mix64's low bits and the shard's
+        # table its bucket from the high ones (RemoteHashTable), so every
+        # shard's keys spread over all of its buckets
         self.buckets_per_shard = max(64, n_buckets // cfe.directory.n_shards)
 
     def _create(self, fe, name):

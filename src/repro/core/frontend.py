@@ -176,6 +176,24 @@ def combine_runs(reqs: Sequence[Tuple[int, int]]) -> List[Tuple[int, int]]:
     return runs
 
 
+def group_by_width(wbuf: Dict[int, bytes]) -> List[Tuple[int, bytes]]:
+    """A write buffer's ``(addr, data)`` entries ordered by data length,
+    stably, where no two entries' byte ranges overlap; in insertion order
+    where some do (then the order decides which bytes land last).  Grouped,
+    a memory-log transaction decodes as one uniform run per width
+    (``oplog.decode_txs_columnar``) instead of entry by entry."""
+    items = list(wbuf.items())
+    if len(items) < 2:
+        return items
+    addrs = np.fromiter(wbuf.keys(), dtype=np.int64, count=len(items))
+    lens = np.fromiter(map(len, wbuf.values()), dtype=np.int64, count=len(items))
+    order = np.argsort(addrs)
+    a, n = addrs[order], lens[order]
+    if np.any(a[:-1] + n[:-1] > a[1:]):
+        return items
+    return [items[i] for i in np.argsort(lens, kind="stable").tolist()]
+
+
 @dataclasses.dataclass
 class ReadPolicy:
     """How a front-end resolves the *target blade* for remote reads.
@@ -1291,7 +1309,7 @@ class FrontEnd:
                 # there), but mirrors apply the stream write-by-write, so a
                 # mirror's opsn copy must never advance past data it is missing
                 # — replica reads gate on it (NVMBackend.replica_whole_seq)
-                entries = [MemLog(a, d) for a, d in h.wbuf.items()]
+                entries = [MemLog(a, d) for a, d in group_by_width(h.wbuf)]
                 entries.append(MemLog(self.backend.name_slot_addr(h.opsn_name),
                                       struct.pack("<Q", h.seq)))
                 payload = encode_tx(entries)

@@ -7,6 +7,15 @@ cells empty) but *vector ops* do: a batch of independent keys walks all its
 chains in doorbell-batched waves (`_lookup`), so `get_many`/`put_many` pay
 one RTT per chain *level* instead of one per node — the batching win the
 paper reserves for pointer structures applies here across keys.
+
+A key's bucket comes from the *high* 32 bits of ``mix64`` by multiply-shift
+range reduction.  The cluster's shard router takes ``mix64 % n_shards``,
+the low bits: a bucket index taken as ``mix64 % n_buckets`` would share
+them, so with power-of-two counts every key of a shard would land in one
+bucket of every ``n_shards`` and the chains would be ``n_shards`` times
+longer than the load factor.  The high bits are independent of the shard,
+so each shard's keys spread over all of its buckets, and an unsharded
+table's buckets are as uniform as before.
 """
 
 from __future__ import annotations
@@ -16,6 +25,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ...obs.profile import count
 from ..frontend import FrontEnd
 from .base import RemoteStructure, mix64, mix64_np
 
@@ -50,13 +60,17 @@ class RemoteHashTable(RemoteStructure):
             self.n_buckets = be.get_name(f"{name}.nbuckets")
 
     def _bucket_addr(self, key: int) -> int:
-        return self.base + (mix64(key & 0xFFFFFFFFFFFFFFFF) % self.n_buckets) * 8
+        # ((h >> 32) * n) >> 32 lies in [0, n) for n < 2^32 (module docstring)
+        h = mix64(key & 0xFFFFFFFFFFFFFFFF)
+        return self.base + (((h >> 32) * self.n_buckets) >> 32) * 8
 
     def _bucket_addrs(self, keys: List[int]) -> List[int]:
-        """Vectorized ``_bucket_addr`` for a whole batch (one numpy pass)."""
+        """Vectorized ``_bucket_addr`` for a whole batch (one numpy pass);
+        the product stays below 2^64, so uint64 gives the same index."""
         ks = np.array([k & 0xFFFFFFFFFFFFFFFF for k in keys], dtype=np.uint64)
-        addrs = self.base + (mix64_np(ks) % np.uint64(self.n_buckets)) * np.uint64(8)
-        return addrs.tolist()
+        hi = mix64_np(ks) >> np.uint64(32)
+        b = (hi * np.uint64(self.n_buckets)) >> np.uint64(32)
+        return (self.base + b * np.uint64(8)).tolist()
 
     def _read_ptr(self, addr: int) -> int:
         return struct.unpack("<Q", self.fe.read(self.h, addr, 8))[0]
@@ -95,7 +109,9 @@ class RemoteHashTable(RemoteStructure):
             ptr = heads[a]
             if ptr:
                 cursors[i] = ptr
+        compared = 0
         while cursors:
+            compared += len(cursors)
             addrs = sorted(set(cursors.values()))
             raws = self.fe.read_many(self.h, [(a, NODE_SIZE) for a in addrs])
             rec = np.frombuffer(b"".join(raws), dtype=NODE_DT)
@@ -109,6 +125,8 @@ class RemoteHashTable(RemoteStructure):
                 elif nxt:
                     nxt_cursors[i] = nxt
             cursors = nxt_cursors
+        count("hash.lookups", len(keys))
+        count("hash.chain_nodes", compared)
         return out
 
     def get_many(self, keys: List[int]) -> List[Optional[int]]:
@@ -134,6 +152,7 @@ class RemoteHashTable(RemoteStructure):
         heads: Dict[int, int] = dict(zip(baddrs, ptrs))
         cursors: Dict[int, int] = {a: p for a, p in heads.items() if p}
         view: Dict[int, Tuple[int, int, int]] = {}
+        compared = 0
         while cursors:
             addrs = sorted(set(cursors.values()))
             raws = fe.prefetch_many(h, [(a, NODE_SIZE) for a in addrs])
@@ -149,8 +168,11 @@ class RemoteHashTable(RemoteStructure):
                         nxt[bucket] = cur  # next wave fetches it
                         break
                     want.discard(node[0])
+                    compared += 1
                     cur = node[2]
             cursors = nxt
+        count("hash.lookups", len(keys))
+        count("hash.chain_nodes", compared)
         return heads, view
 
     def _apply_puts(self, pairs, key_baddrs, heads, view) -> None:
@@ -179,7 +201,10 @@ class RemoteHashTable(RemoteStructure):
 
         def charge_read(addr: int, size: int) -> None:
             # the charge-side mirror of fe.read: write buffer -> cache ->
-            # remote round; the *value* comes from the local view
+            # remote round; the *value* comes from the local view.  A miss
+            # on a head or node the view holds admits the view's bytes,
+            # which are the device's (a write of this batch is in wbuf, or
+            # flushed), instead of fetching them again
             nonlocal acc, busy
             busy += cpu_node
             if addr in wbuf:
@@ -196,7 +221,14 @@ class RemoteHashTable(RemoteStructure):
             acc = 0.0
             busy = 0.0
             tgt = fe._read_target(h)
-            data = tgt.fetch(addr, size)
+            if size == NODE_SIZE:
+                node = view.get(addr)
+                data = None if node is None else pack(*node)
+            else:
+                head = heads.get(addr)
+                data = None if head is None else pack_ptr(head)
+            if data is None:
+                data = tgt.fetch(addr, size)
             st.rdma_reads += 1
             st.bytes_read += size
             if tgt.is_replica:
