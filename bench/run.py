@@ -20,6 +20,16 @@ plain reference (``bench/reference.py``), then read back from the blades
 with the page caches emptied, and each mirror arena compared with its
 primary.
 
+A traffic file may state a ``fault``, such as ``{"kind":
+"blade_fails_permanently", "blade": 1, "at": 0.4}``: at that share of the
+window, between two batches, the blade fails for good.  The store has to
+find the failure itself, on its next call that needs the blade, and
+promote the blade's mirror; the harness only records when the blade went
+and which shards it held, and counts the promotions against the one the
+file asks for.  After the window every key of the lost shards is read
+back as well, from the promoted blade.  Without a ``fault`` none of this
+runs.
+
 With ``--trace 0`` the last line of standard output carries the cell's
 end-to-end metrics; with ``--trace 1`` the store calls and the device arena
 are wrapped (``bench/spans.py``), a few seconds of the window are traced
@@ -60,6 +70,8 @@ from reference import Reference, replay  # noqa: E402
 DRAIN_LIMIT_S = 60.0        # an op due in the window may complete this late
 READBACK_KEYS = 16384       # keys read back from the blades after the window
 TRACE_START, TRACE_SECONDS = 0.4, 3.0   # traced sub-window: start share, length
+FAULT_TRACE_LEAD_S = 1.0    # where a blade fails, the trace starts this long before
+FAULT_KINDS = ("blade_fails_permanently",)
 OUT_DIR = ROOT / ".bench_out"
 
 
@@ -145,15 +157,18 @@ def build(config: Dict):
     return cluster, cfe, store
 
 
-def warm_arena_programs(config: Dict) -> int:
+def warm_arena_programs(config: Dict, clone: bool = False) -> int:
     """Compile, before the cluster exists, every arena program a wave can
     need, so that none compiles in the window: for each power-of-two wave
     bucket a one-run read (slice), a many-run read (gather), a write
     (scatter) and a device copy into the mirrors, all through
     ``DeviceArena``'s own methods on scratch arenas of the cluster's size,
-    which are freed before the cluster is built.  Returns the programs
-    compiled; where the arena's interface has changed, warms nothing and
-    says so."""
+    which are freed before the cluster is built.  With `clone`, for a cell
+    whose blade fails, also a whole-arena clone, the copy a mirror's
+    promotion makes twice (``NVMBackend.promote_mirror``); the reads of
+    the promoted blade's ``reboot`` fall in the buckets above.  Returns the
+    arena programs compiled; where the arena's interface has changed, warms
+    nothing and says so."""
     import gc
 
     from repro.core import devmem
@@ -173,6 +188,8 @@ def warm_arena_programs(config: Dict) -> int:
             a.copy_runs(np.array([0]), np.array([size]), np.array([size]),
                         into=arenas[1:])
             size *= 2
+        if clone:
+            arenas.append(arenas[0].clone())
         for a in arenas:   # wait for their last programs, so they free at once
             a.read_runs([(0, 1)])
     except (AttributeError, TypeError) as e:
@@ -279,6 +296,35 @@ class Harness:
             return False, None
 
 
+# --------------------------------------------------------------- faults
+def fault_hook(fault: Dict, seconds: float, cluster, harness: Harness, out: Dict):
+    """The window's ``(at_s, fn)`` hook for a traffic file's `fault`: between
+    two batches the blade fails for good, and `out` records when (seconds
+    from the window's start), the shards it held then, and how many store
+    calls the harness had logged.  Finding the failure and promoting the
+    mirror is left to the store."""
+    if fault["kind"] not in FAULT_KINDS:
+        raise ValueError(f"unknown fault kind {fault['kind']!r}; known: {FAULT_KINDS}")
+    blade = int(fault["blade"])
+    if blade not in cluster.blades:
+        raise ValueError(f"the cluster has no blade {blade}")
+
+    def fail(t0):
+        out["blade"] = blade
+        out["shards"] = sorted(cluster.directory.shards_on(blade))
+        cluster.blades[blade].fail_permanently()
+        out["t"] = time.perf_counter() - t0
+        out["events"] = len(harness.events)
+
+    return float(fault["at"]) * seconds, fail
+
+
+def on_shards(cfe, keys, shards) -> np.ndarray:
+    """Which of `keys` the router puts on one of `shards`."""
+    shard_of, lost = cfe.directory.shard_of, set(shards)
+    return np.fromiter((shard_of(k) in lost for k in keys), bool, len(keys))
+
+
 # --------------------------------------------------------------- checks
 def clear_page_caches(cfe) -> None:
     for fe in cfe.fes.values():
@@ -355,6 +401,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         else:
             config[k] = v
     traffic = {**cell["traffic_data"], **(traffic_overrides or {})}
+    fault = traffic.get("fault")
     setup: Dict[str, float] = {}
 
     t = time.perf_counter()
@@ -376,7 +423,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     setup["traffic_s"] = time.perf_counter() - t
 
     t = time.perf_counter()
-    n_warmed = warm_arena_programs(config)
+    n_warmed = warm_arena_programs(config, clone=bool(fault))
     setup["arena_programs_s"] = time.perf_counter() - t
     t = time.perf_counter()
     cluster, cfe, store = build(config)
@@ -386,12 +433,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     setup["load_s"] = time.perf_counter() - t
     if tamper is not None:
         tamper(cluster, cfe, store)
+    failovers0 = cluster.failovers
 
     spans = None
     if trace:
         from spans import Spans
 
-        spans = Spans()
+        spans = Spans(failover=bool(fault))
         spans.install()
         profile.enable()
     t = time.perf_counter()
@@ -417,7 +465,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
         trace_dir = OUT_DIR / "trace"
         shutil.rmtree(trace_dir, ignore_errors=True)
-        t_on = TRACE_START * seconds
+        # a fault is traced with its promotion, the tracer's start-up stall
+        # served before the blade fails
+        t_on = (max(0.0, float(fault["at"]) * seconds - FAULT_TRACE_LEAD_S) if fault
+                else TRACE_START * seconds)
         t_off = t_on + min(TRACE_SECONDS, 0.3 * seconds)
 
         options = jax.profiler.ProfileOptions()
@@ -435,6 +486,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             jax.profiler.stop_trace()
 
         hooks += [(t_on, trace_on), (t_off, trace_off)]
+    faulted: Dict = {}
+    if fault:
+        hooks.append(fault_hook(fault, seconds, cluster, harness, faulted))
     done = harness.serve(win_ops, limit_s=seconds + DRAIN_LIMIT_S, hooks=hooks)
     if "ann" in traced and "bytes" not in traced:
         trace_off(None)
@@ -465,9 +519,26 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                                 replace=False)].tolist()
     seen = set(written)
     rb_keys = (written + [k for k in sample if k not in seen])[:READBACK_KEYS]
+    lost = None
+    if faulted:
+        # every key written, and every key of the lost shards, is read back:
+        # the promoted blade has to hold each acknowledged write
+        lost = on_shards(cfe, win_ops.key.tolist(), faulted["shards"])
+        keys = rec.keys.tolist()
+        on_lost = [k for k, on in zip(keys, on_shards(cfe, keys, faulted["shards"])) if on]
+        seen = set(rb_keys)
+        rb_keys += [k for k in dict.fromkeys(written + on_lost) if k not in seen]
+        n_before = len({k for ev in harness.events[:faulted["events"]] if ev[0] == "put"
+                        for k in ev[1]} & set(on_lost))
     clear_page_caches(cfe)
     readback_bad = readback(store, ref, rb_keys)
     mirror_bad = mirror_bytes_differ(cluster)
+    promoted = cluster.failovers - failovers0
+    asked = 1 if cell["traffic_data"].get("fault") else 0   # as the cell's file states
+    if faulted:
+        log(f"blade {faulted['blade']} failed at {faulted['t']:.3f} s holding shards "
+            f"{faulted['shards']}; mirror promotions {promoted}; {len(on_lost)} keys "
+            f"there, {n_before} of them written before the failure, all read back")
     for err in harness.errors[:5]:
         log("store call failed: " + err)
     if first:
@@ -477,6 +548,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         "wrong_answers": {"value": wrong, "limit": 0},
         "readback_mismatches": {"value": readback_bad, "limit": 0},
         "mirror_bytes_differ": {"value": mirror_bad, "limit": 0},
+        "failovers_missing": {"value": abs(promoted - asked), "limit": 0},
     }
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     log(f"answers compared: {compared} in window and warm-up, "
@@ -487,6 +559,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     record = {
         "cell": name, "seconds": seconds, "ops": len(win_ops),
         "completed_in_window": in_window, "latency_s": lat,
+        "due_s": win_ops.due, "done_s": done,
+        "fault": dict(faulted, lost=lost) if faulted else None,
         "stats0": stats0, "stats1": stats1, "span0": span0, "span1": span1,
         "profile": prof, "traced_bytes": traced.get("bytes"),
     }

@@ -15,6 +15,13 @@ Each span is also a ``jax.profiler.TraceAnnotation`` named ``bench.<name>``,
 so the profiler's trace shows what the host was doing while the device sat
 idle.  The wrappers are installed only for a ``--trace 1`` run; a
 ``--trace 0`` run measures the store as it is.
+
+In a cell whose traffic fails a blade, ``Spans(failover=True)`` also wraps
+the mirror promotion's other phases, ``NVMBackend.reboot`` and
+``ClusterFrontEnd.recover_blade`` (which holds the clones and the reboot),
+as ``bench.failover.<method>`` spans.  Every wrapped method keeps its calls
+and the seconds of its outermost calls; a clone's span waits for its device
+copy, which would otherwise land in the first read after it.
 """
 
 from __future__ import annotations
@@ -28,6 +35,7 @@ import jax
 import numpy as np
 
 ARENA_METHODS = ("read_runs", "write_runs", "flush", "copy_runs", "clone")
+FAILOVER_METHODS = ("reboot", "recover_blade")
 
 
 def _run_bytes(method: str, arena, args, kwargs) -> int:
@@ -48,13 +56,15 @@ def _run_bytes(method: str, arena, args, kwargs) -> int:
 
 
 class Spans:
-    def __init__(self) -> None:
+    def __init__(self, failover: bool = False) -> None:
+        self.failover = failover
         self.store_s = 0.0
         self.arena_s = 0.0
-        self.arena_calls: Counter = Counter()
+        self.calls: Counter = Counter()
+        self.seconds: Counter = Counter()
         self.arena_bytes = 0
         self._depth = 0
-        self._saved: Dict[str, Callable] = {}
+        self._saved: list = []
 
     # --------------------------------------------------------- store calls
     @contextlib.contextmanager
@@ -66,44 +76,61 @@ class Spans:
             finally:
                 self.store_s += time.perf_counter() - t0
 
-    # ------------------------------------------------------- arena methods
-    def install(self) -> None:
+    # ------------------------------------------------------ wrapped methods
+    def _targets(self):
         from repro.core.devmem import DeviceArena
 
-        for method in ARENA_METHODS:
-            orig = getattr(DeviceArena, method)
-            self._saved[method] = orig
-            setattr(DeviceArena, method, self._wrap(method, orig))
+        targets = [(DeviceArena, m) for m in ARENA_METHODS]
+        if self.failover:
+            from repro.cluster.router import ClusterFrontEnd
+            from repro.core.backend import NVMBackend
+
+            targets += [(NVMBackend, "reboot"), (ClusterFrontEnd, "recover_blade")]
+        return targets
+
+    def install(self) -> None:
+        for cls, method in self._targets():
+            orig = getattr(cls, method)
+            self._saved.append((cls, method, orig))
+            setattr(cls, method, self._wrap(method, orig))
 
     def uninstall(self) -> None:
-        from repro.core.devmem import DeviceArena
-
-        for method, orig in self._saved.items():
-            setattr(DeviceArena, method, orig)
+        for cls, method, orig in self._saved:
+            setattr(cls, method, orig)
         self._saved.clear()
 
     def _wrap(self, method: str, orig: Callable) -> Callable:
-        label = f"bench.arena.{method}"
+        arena = method in ARENA_METHODS
+        label = f"bench.{'arena' if arena else 'failover'}.{method}"
 
-        def wrapper(arena, *args, **kwargs):
-            self.arena_calls[method] += 1
-            self.arena_bytes += _run_bytes(method, arena, args, kwargs)
-            if self._depth:
-                return orig(arena, *args, **kwargs)
-            self._depth += 1
+        def wrapper(obj, *args, **kwargs):
+            self.calls[method] += 1
+            if arena:
+                self.arena_bytes += _run_bytes(method, obj, args, kwargs)
+                if self._depth:
+                    return orig(obj, *args, **kwargs)
+                self._depth += 1
             t0 = time.perf_counter()
             try:
                 with jax.profiler.TraceAnnotation(label):
-                    return orig(arena, *args, **kwargs)
+                    out = orig(obj, *args, **kwargs)
+                    if method == "clone":
+                        out._array.block_until_ready()
+                    return out
             finally:
-                self.arena_s += time.perf_counter() - t0
-                self._depth -= 1
+                dt = time.perf_counter() - t0
+                self.seconds[method] += dt
+                if arena:
+                    self.arena_s += dt
+                    self._depth -= 1
 
         wrapper.__name__ = method
         wrapper.__doc__ = orig.__doc__
         return wrapper
 
     def snapshot(self) -> Dict[str, float]:
+        methods = ARENA_METHODS + (FAILOVER_METHODS if self.failover else ())
         return {"store_s": self.store_s, "arena_s": self.arena_s,
                 "arena_bytes": self.arena_bytes,
-                **{f"calls.{m}": self.arena_calls[m] for m in ARENA_METHODS}}
+                **{f"calls.{m}": self.calls[m] for m in methods},
+                **{f"seconds.{m}": self.seconds[m] for m in methods}}
