@@ -202,7 +202,7 @@ def cost_runs(args, run) -> None:
     import numpy as np
 
     import traffic as tr
-    from reference import Reference, replay
+    from reference import replay
 
     from repro.obs import profile
 
@@ -214,7 +214,7 @@ def cost_runs(args, run) -> None:
     n_windows = 2 * len(args.seeds)
     n_extra = (tr.inserts_needed(traffic, warm_s)
                + n_windows * tr.inserts_needed(traffic, args.seconds))
-    rec = tr.make_records(args.seeds[0], int(config["recordcount"]), n_extra)
+    rec = run.records_for(config, args.seeds[0], n_extra)
     run.warm_arena_programs(config)
     cluster, cfe, store = run.build(config)
     run.load_records(store, config, rec)
@@ -246,9 +246,7 @@ def cost_runs(args, run) -> None:
                 "failed": int(np.sum(~np.isfinite(done))),
                 "arena_reads": profile.snapshot().get("arena.reads", {}).get("count")}),
                 flush=True)
-    ref = Reference(rec.keys.tolist(), rec.values.tolist(),
-                    ordered=config["structure"] == "bptree")
-    compared, wrong, first = replay(ref, harness.events)
+    compared, wrong, first = replay(run.reference_for(config, rec), harness.events)
     print(json.dumps({"workload": args.workload, "answers_compared": compared,
                       "wrong_answers": wrong, "first_wrong": first,
                       "store_errors": harness.errors[:3]}), flush=True)

@@ -1,9 +1,11 @@
 """Plain reference for the benchmark's key/value semantics.
 
 A dict of the current value of every key, and, where the cell scans, the
-sorted list of keys.  It imports nothing of the store and takes nothing the
-store made: it is built from the same seeded records and replays the same
-ops in the order the harness issued them.
+sorted list of keys.  Wide records (``RecordReference``) are rows of one
+``uint8`` array with a key -> row map, handed out as ``bytes``, so that
+every comparison is byte for byte.  It imports nothing of the store and takes nothing the store
+made: it is built from the same seeded records and replays the same ops in
+the order the harness issued them.
 
 The harness logs each store call of a run as one event, in issue order:
 
@@ -22,6 +24,8 @@ from __future__ import annotations
 import bisect
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 
 class Reference:
     def __init__(self, keys: Sequence[int], values: Sequence[int], ordered: bool):
@@ -39,7 +43,40 @@ class Reference:
     def scan(self, lo: int, hi: int) -> List[Tuple[int, int]]:
         i = bisect.bisect_left(self.sorted, lo)
         j = bisect.bisect_right(self.sorted, hi)
-        return [(k, self.d[k]) for k in self.sorted[i:j]]
+        return [(k, self.get(k)) for k in self.sorted[i:j]]
+
+
+class RecordReference(Reference):
+    """Wide values as ``bytes``: the loaded records are the rows of one
+    ``uint8`` array (copied), row ``row[k]`` key k's; a key inserted later
+    keeps its record in ``d``."""
+
+    def __init__(self, keys: Sequence[int], rows: np.ndarray, ordered: bool):
+        super().__init__((), (), ordered)
+        self.rows = np.array(rows, np.uint8)
+        self.row: Dict[int, int] = dict(zip(keys, range(len(keys))))
+        self.sorted = sorted(self.row) if ordered else None
+
+    def get(self, k: int) -> Optional[bytes]:
+        r = self.row.get(k)
+        return self.d.get(k) if r is None else self.rows[r].tobytes()
+
+    def put(self, k: int, v: bytes) -> None:
+        r = self.row.get(k)
+        if r is None:
+            super().put(k, bytes(v))
+        else:
+            self.rows[r] = np.frombuffer(v, np.uint8)
+
+
+def _describe(got, want) -> str:
+    """An answer against the reference's; two records by their first
+    differing byte."""
+    if isinstance(want, bytes) and isinstance(got, (bytes, bytearray)) \
+            and len(got) == len(want):
+        i = next(i for i in range(len(want)) if got[i] != want[i])
+        return f"byte {i} is {got[i]}, not {want[i]}"
+    return f"{str(got)[:80]} != {str(want)[:80]}"
 
 
 def replay(ref: Reference, events: Iterable[tuple]) -> Tuple[int, int, str]:
@@ -55,7 +92,7 @@ def replay(ref: Reference, events: Iterable[tuple]) -> Tuple[int, int, str]:
                 compared += 1
                 if got != want:
                     wrong += 1
-                    first = first or f"get {k}: {got} != {want}"
+                    first = first or f"get {k}: {_describe(got, want)}"
             if len(answers) != len(keys):
                 wrong += abs(len(keys) - len(answers))
                 first = first or f"get_many: {len(answers)} answers for {len(keys)} keys"

@@ -20,6 +20,25 @@ plain reference (``bench/reference.py``), then read back from the blades
 with the page caches emptied, and each mirror arena compared with its
 primary.
 
+Records.  Keys are 8-byte integers.  A configuration without a
+``record`` group has 8-byte integer values (``value_bytes`` 8): the store
+is handed ``int`` values and answers ``int``.  One with ``"record":
+{"fieldcount": F, "fieldlength": L}`` has YCSB's records, ``value_bytes``
+= F x L (else the run stops before it starts): the structure is built with
+``value_bytes=F*L`` (a constructor without that parameter is refused
+before the cluster is built), handed each record as ``bytes`` of exactly
+that length, and has to answer ``bytes`` (or ``bytearray``) equal byte for
+byte.  A read returns the whole record (YCSB's ``readallfields``).  An
+insert writes a whole record; an update replaces one field (YCSB's
+default, ``writeallfields`` false) and is a read-modify-write, as YCSB's
+RocksDB binding does it: its key joins the batch's reads in the one
+``get_many``, the harness replaces the field in the answer (two updates
+of one key apply in op order), and the new records go out in the batch's
+one ``put_many``, whose return completes the update.  The reference
+checks that read as any other.  ``records_for`` and ``reference_for``
+make the records and the reference for every entry point (this module,
+``sweep.py``, ``program_spans.py``).
+
 A traffic file may state a ``fault``, such as ``{"kind":
 "blade_fails_permanently", "blade": 1, "at": 0.4}``: at that share of the
 window, between two batches, the blade fails for good.  The store has to
@@ -65,7 +84,7 @@ sys.path.insert(0, str(BENCH_DIR))
 sys.path.insert(1, str(ROOT / "src"))
 
 import traffic as tr  # noqa: E402  (bench/traffic.py)
-from reference import Reference, replay  # noqa: E402
+from reference import RecordReference, Reference, replay  # noqa: E402
 
 DRAIN_LIMIT_S = 60.0        # an op due in the window may complete this late
 READBACK_KEYS = 16384       # keys read back from the blades after the window
@@ -80,16 +99,18 @@ class NoAccelerator(RuntimeError):
 
 
 # ------------------------------------------------------------------ cells
-def load_cell(name: str) -> Dict:
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+def load_cell(name: str, index: pathlib.Path = ROOT / "BENCHMARK.json",
+              workloads: pathlib.Path = BENCH_DIR / "workloads") -> Dict:
+    """Cell `name` of `index`, with its configuration (its ``file`` taken
+    from `index`'s directory) and its traffic file from `workloads`."""
+    bench = json.loads(index.read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = dict(cells[name])
     cfg_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
-    cell["config_data"] = json.loads((ROOT / cfg_entry["file"]).read_text())
-    cell["traffic_data"] = json.loads(
-        (BENCH_DIR / "workloads" / f"{cell['traffic']}.json").read_text())
+    cell["config_data"] = json.loads((index.parent / cfg_entry["file"]).read_text())
+    cell["traffic_data"] = json.loads((workloads / f"{cell['traffic']}.json").read_text())
     if cell["traffic_data"]["config"] != cell["config"]:
         raise SystemExit(f"{cell['traffic']}.json is for {cell['traffic_data']['config']}, "
                          f"the cell for {cell['config']}")
@@ -125,19 +146,36 @@ def devices(chips: int, require_accelerator: bool):
 
 
 # ------------------------------------------------------------------ store
+def structure_class(name: str):
+    """The store's class for a configuration's ``structure``."""
+    from repro.cluster import ShardedBPTree, ShardedHashTable
+
+    classes = {"hash": ShardedHashTable, "bptree": ShardedBPTree}
+    if name not in classes:
+        raise ValueError(f"unknown structure {name!r}")
+    return classes[name]
+
+
 def build(config: Dict):
     """The cluster, its client front-end and the structure, as the
     configuration states them.  Every field of its ``frontend`` group is
     passed to ``FEConfig`` through the named preset; a field ``FEConfig``
-    lacks, or a record width the store does not have, is an error, so the
-    file states exactly what runs."""
+    lacks, or a record width the structure cannot take, is an error, so
+    the file states exactly what runs."""
     import dataclasses
+    import inspect
 
-    from repro.cluster import ClusterFrontEnd, NVMCluster, ShardedBPTree, ShardedHashTable
+    from repro.cluster import ClusterFrontEnd, NVMCluster
     from repro.core import FEConfig
 
-    if (config["key_bytes"], config["value_bytes"]) != (8, 8):
-        raise ValueError("the store keeps 8-byte integer keys and values")
+    cls = structure_class(config["structure"])
+    kwargs = {"n_buckets": config["n_buckets"]} if config["structure"] == "hash" else {}
+    shape = tr.record_shape(config)
+    if shape is not None:
+        if "value_bytes" not in inspect.signature(cls).parameters:
+            raise ValueError("the store keeps 8-byte integer keys and values: "
+                             f"{cls.__name__} takes no value_bytes")
+        kwargs["value_bytes"] = shape.width
     cl = config["cluster"]
     cluster = NVMCluster(n_blades=cl["n_blades"], n_shards=cl["n_shards"],
                          num_mirrors=cl["num_mirrors"],
@@ -148,13 +186,7 @@ def build(config: Dict):
     if set(fe) - known:
         raise ValueError(f"FEConfig has no field {sorted(set(fe) - known)}")
     cfe = ClusterFrontEnd(cluster, preset(**fe), fe_id=0)
-    if config["structure"] == "hash":
-        store = ShardedHashTable(cfe, "ycsb", n_buckets=config["n_buckets"])
-    elif config["structure"] == "bptree":
-        store = ShardedBPTree(cfe, "ycsb")
-    else:
-        raise ValueError(f"unknown structure {config['structure']!r}")
-    return cluster, cfe, store
+    return cluster, cfe, cls(cfe, "ycsb", **kwargs)
 
 
 def warm_arena_programs(config: Dict, clone: bool = False) -> int:
@@ -206,6 +238,24 @@ def heap_bytes_in_use(cluster) -> int:
                for be in cluster.blades.values() if be.alive)
 
 
+def records_for(config: Dict, seed: int, n_extra: int) -> tr.Records:
+    """The seeded records a run of `config` loads, 8-byte integers or
+    records of the shape its ``record`` group states, and `n_extra` fresh
+    keys for inserts.  A ``record`` group that does not add up to
+    ``value_bytes`` is refused here, before the cluster is built."""
+    return tr.make_records(seed, int(config["recordcount"]), n_extra,
+                           tr.record_shape(config))
+
+
+def reference_for(config: Dict, rec: tr.Records) -> Reference:
+    """The plain reference over `rec`, as `config`'s structure answers:
+    ordered for the B+tree's scans, byte for byte for records."""
+    ordered = config["structure"] == "bptree"
+    if rec.shape is None:
+        return Reference(rec.keys.tolist(), rec.values.tolist(), ordered)
+    return RecordReference(rec.keys.tolist(), rec.values, ordered)
+
+
 def load_records(store, config: Dict, rec: tr.Records) -> None:
     """Insert every record in one ``put_many``, then drain: the hash table
     in load order, the B+tree in key order (its bulk-build path).  One batch
@@ -214,7 +264,8 @@ def load_records(store, config: Dict, rec: tr.Records) -> None:
     if config["structure"] == "bptree":
         order = np.argsort(keys)
         keys, vals = keys[order], vals[order]
-    store.put_many(list(zip(keys.tolist(), vals.tolist())))
+    vals = vals.tolist() if rec.shape is None else [v.tobytes() for v in vals]
+    store.put_many(list(zip(keys.tolist(), vals)))
     store.drain()
 
 
@@ -261,13 +312,18 @@ class Harness:
             j = min(int(np.searchsorted(ops.due, now, side="right")), i + self.max_batch)
             k = kinds[i:j]
             idx = np.arange(i, j)
-            reads = idx[k == read_c]
+            gets = k == read_c
+            if ops.field is not None:   # one-field updates read their record first
+                gets |= ops.field[i:j] >= 0
+            reads = idx[gets]
+            answered = ([], [])
             if len(reads):
                 keys = ops.key[reads].tolist()
                 ok, got = self._guard("get_many", lambda: self.store.get_many(keys))
                 if ok:
                     self.events.append(("get", keys, got))
-                    done[reads] = time.perf_counter() - t0
+                    done[reads[k[gets] == read_c]] = time.perf_counter() - t0
+                    answered = (keys, got)
             for s in idx[k == scan_c]:
                 lo, hi = int(ops.key[s]), int(ops.hi[s])
                 ok, rows = self._guard("range_scan", lambda: self.store.range_scan(lo, hi))
@@ -275,9 +331,12 @@ class Harness:
                     self.events.append(("scan", lo, hi, rows))
                     done[s] = time.perf_counter() - t0
             writes = idx[(k == upd_c) | (k == ins_c)]
+            if ops.field is None:
+                vals = ops.value[writes].tolist()
+            else:
+                writes, vals = self._new_records(ops, writes, answered)
             if len(writes):
                 keys = ops.key[writes].tolist()
-                vals = ops.value[writes].tolist()
                 ok, _ = self._guard("put_many",
                                     lambda: self.store.put_many(list(zip(keys, vals))))
                 if ok:
@@ -285,6 +344,32 @@ class Harness:
                     done[writes] = time.perf_counter() - t0
             i = j
         return done
+
+    @staticmethod
+    def _new_records(ops: tr.Ops, writes: np.ndarray, answered):
+        """The records a batch's writes put, in op order: a whole record as
+        drawn, or one field replaced in the record the batch's ``get_many``
+        answered, or an earlier write of the batch left.  A one-field
+        update whose record did not come back whole cannot be built: it
+        is not sent, and fails."""
+        shape, latest = ops.shape, dict(zip(*answered))
+        sent, vals = [], []
+        for w in writes.tolist():
+            key, f = int(ops.key[w]), int(ops.field[w])
+            if f < 0:
+                new = ops.data[w].tobytes()
+            else:
+                old = latest.get(key)
+                if old is None or len(old) != shape.width:
+                    continue
+                part = shape.field(f)
+                new = bytearray(old)
+                new[part] = ops.data[w, part].tobytes()
+                new = bytes(new)
+            latest[key] = new
+            sent.append(w)
+            vals.append(new)
+        return np.array(sent, np.int64), vals
 
     def _guard(self, name: str, fn: Callable):
         """(True, result), or (False, None) where the call raised: its ops
@@ -402,6 +487,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             config[k] = v
     traffic = {**cell["traffic_data"], **(traffic_overrides or {})}
     fault = traffic.get("fault")
+    tr.record_shape(config)     # a record group that does not add up stops here
     setup: Dict[str, float] = {}
 
     t = time.perf_counter()
@@ -414,7 +500,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     t = time.perf_counter()
     warm_s = float(traffic["warmup_s"])
     n_extra = (tr.inserts_needed(traffic, warm_s) + tr.inserts_needed(traffic, seconds))
-    rec = tr.make_records(seed, int(config["recordcount"]), n_extra)
+    rec = records_for(config, seed, n_extra)
     r_warm, r_win = tr.rngs(seed + 1, 2)
     warm_ops = tr.make_ops(traffic, rec, int(r_warm.integers(1 << 62)), warm_s)
     n_warm_ins = int(np.sum(warm_ops.kind == tr.KINDS.index(tr.INSERT)))
@@ -510,8 +596,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     lat = done - win_ops.due
     failed = int(np.sum(~np.isfinite(done)))
     in_window = int(np.sum(done <= seconds))
-    ref = Reference(rec.keys.tolist(), rec.values.tolist(),
-                    ordered=config["structure"] == "bptree")
+    ref = reference_for(config, rec)
     compared, wrong, first = replay(ref, harness.events)
     written = sorted({k for ev in harness.events if ev[0] == "put" for k in ev[1]})
     rb = np.random.default_rng(seed % (1 << 64))

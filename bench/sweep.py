@@ -54,7 +54,7 @@ def main(argv=None) -> int:
     worst = max(rates)
     n_extra = sum(tr.inserts_needed({**c["traffic_data"], "rate_ops_s": worst},
                                     args.seconds) * len(rates) for c in cells) + 1
-    rec = tr.make_records(args.seed, int(config["recordcount"]), n_extra)
+    rec = run.records_for(config, args.seed, n_extra)
     cluster, cfe, store = run.build(config)
     run.load_records(store, config, rec)
     print(f"set-up {time.perf_counter() - t:.3f} s on {devs[0].device_kind}",

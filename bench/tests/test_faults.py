@@ -34,13 +34,18 @@ def half_of_writes(cluster, cfe, store):
     _wrap(store, "put_many", lambda orig: lambda pairs: orig(pairs[:len(pairs) // 2]))
 
 
+def _altered(v):
+    """An 8-byte value plus one, or a record with its first byte flipped."""
+    return v + 1 if isinstance(v, int) else bytes([v[0] ^ 1]) + bytes(v[1:])
+
+
 def answer_altered(cluster, cfe, store):
     """get_many alters one answer where it is produced."""
     def make(orig):
         def get_many(keys):
             out = orig(keys)
             if out and out[-1] is not None:
-                out[-1] += 1
+                out[-1] = _altered(out[-1])
             return out
         return get_many
     _wrap(store, "get_many", make)
@@ -53,7 +58,7 @@ def scan_row_altered(cluster, cfe, store):
             rows = orig(lo, hi)
             if rows:
                 k, v = rows[-1]
-                rows[-1] = (k, v + 1)
+                rows[-1] = (k, _altered(v))
             return rows
         return range_scan
     _wrap(store, "range_scan", make)

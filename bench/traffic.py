@@ -6,6 +6,15 @@ and the measured window each op's due time, kind, key, value and scan
 length.  The generator reads one traffic file (``bench/workloads/*.json``)
 and knows no cell by name.
 
+A configuration with a ``record`` group, ``{"fieldcount": F,
+"fieldlength": L}``, has YCSB's records (``CoreWorkload``): each value is
+``F x L`` random bytes, held as one ``uint8`` array of rows.  An update
+then carries, as YCSB's default ``writeallfields=false`` has it, one field
+index drawn uniformly from ``[0, F)`` and ``L`` new bytes; an insert
+carries a whole record.  These come from generators spawned after the
+8-byte value's, so the streams of a configuration without ``record`` are
+what they were.
+
 The zipfian sampler is YCSB's (Cooper et al., SoCC 2010; Gray et al.'s
 inverse CDF over the exact zeta sum), copied from ``benchmarks/keydist.py``.
 Ranks map to keys through a seeded permutation of the loaded keys, so every
@@ -25,7 +34,7 @@ than any change of the store.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -98,26 +107,69 @@ def stratified_lengths(rng: np.random.Generator, n: int, lo: int, hi: int) -> np
 
 
 # ------------------------------------------------------------- records
+@dataclass(frozen=True)
+class RecordShape:
+    """YCSB's record: `fieldcount` fields of `fieldlength` bytes each."""
+    fieldcount: int
+    fieldlength: int
+
+    @property
+    def width(self) -> int:
+        return self.fieldcount * self.fieldlength
+
+    def field(self, f: int) -> slice:
+        return slice(f * self.fieldlength, (f + 1) * self.fieldlength)
+
+
+def record_shape(config: Dict) -> Optional[RecordShape]:
+    """The configuration's record shape, None for 8-byte integer values.
+    Keys are 8-byte integers either way; a ``record`` group has to state
+    exactly ``value_bytes``."""
+    if config["key_bytes"] != 8:
+        raise ValueError("the store keeps 8-byte integer keys")
+    group = config.get("record")
+    if group is None:
+        if config["value_bytes"] != 8:
+            raise ValueError("values other than 8-byte integers need a record group")
+        return None
+    if set(group) != {"fieldcount", "fieldlength"}:
+        raise ValueError(f"a record group has fieldcount and fieldlength, not {sorted(group)}")
+    shape = RecordShape(int(group["fieldcount"]), int(group["fieldlength"]))
+    if shape.fieldcount < 1 or shape.fieldlength < 1 or shape.width != config["value_bytes"]:
+        raise ValueError(f"value_bytes {config['value_bytes']} is not fieldcount x "
+                         f"fieldlength = {shape.fieldcount} x {shape.fieldlength}")
+    return shape
+
+
 @dataclass
 class Records:
     keys: np.ndarray       # loaded keys, int64, in load order
-    values: np.ndarray     # their values
+    values: np.ndarray     # their values: int64, or uint8 rows of `shape.width` bytes
     extra: np.ndarray      # fresh keys for inserts, none of them loaded
     perm: np.ndarray       # rank -> index into `keys` (point ops) / `sorted_keys` (scans)
     sorted_keys: np.ndarray
+    shape: Optional[RecordShape] = None
 
 
-def make_records(seed: int, n: int, n_extra: int) -> Records:
-    r_keys, r_vals, r_perm = rngs(seed, 3)
+def make_records(seed: int, n: int, n_extra: int,
+                 shape: Optional[RecordShape] = None) -> Records:
+    if shape is not None:
+        r_keys, _, r_perm, r_rows = rngs(seed, 4)
+    else:
+        r_keys, r_vals, r_perm = rngs(seed, 3)
     want = n + n_extra
     keys = np.empty(0, np.int64)
     while len(keys) < want:
         draw = r_keys.integers(KEY_LO, KEY_HI, size=want + want // 64 + 64, dtype=np.int64)
         keys = np.unique(np.concatenate([keys, draw]))
     keys = r_keys.permutation(keys)[:want]
-    vals = r_vals.integers(VAL_LO, VAL_HI, size=n, dtype=np.int64)
+    if shape is not None:
+        vals = r_rows.integers(0, 256, size=(n, shape.width), dtype=np.uint8)
+    else:
+        vals = r_vals.integers(VAL_LO, VAL_HI, size=n, dtype=np.int64)
     return Records(keys=keys[:n], values=vals, extra=keys[n:],
-                   perm=r_perm.permutation(n), sorted_keys=np.sort(keys[:n]))
+                   perm=r_perm.permutation(n), sorted_keys=np.sort(keys[:n]),
+                   shape=shape)
 
 
 # ------------------------------------------------------------ op stream
@@ -127,7 +179,13 @@ class Ops:
     kind: np.ndarray       # index into KINDS
     key: np.ndarray        # read/update/insert: the key; scan: low key
     hi: np.ndarray         # scan: high key (inclusive); else 0
-    value: np.ndarray      # update/insert: the value written; else 0
+    value: np.ndarray      # 8-byte values: an update's or insert's value; else 0
+    # records only: the field an update replaces, -1 for a whole record
+    # (and for reads and scans), and the new bytes at their place in a
+    # record's row, uint8 [n, width]
+    field: Optional[np.ndarray] = None
+    data: Optional[np.ndarray] = None
+    shape: Optional[RecordShape] = None
 
     def __len__(self) -> int:
         return len(self.due)
@@ -160,7 +218,28 @@ def make_ops(traffic: Dict, rec: Records, seed: int, seconds: float,
     key[ins] = rec.extra[first_extra:first_extra + len(ins)]
     value[kind == KINDS.index(READ)] = 0
     value[scans] = 0
-    return Ops(due=due, kind=kind, key=key, hi=hi, value=value)
+    ops = Ops(due=due, kind=kind, key=key, hi=hi, value=value)
+    if rec.shape is not None:
+        _record_writes(ops, rec.shape, *rngs(seed, 7)[5:])
+    return ops
+
+
+def _record_writes(ops: Ops, shape: RecordShape,
+                   r_field: np.random.Generator, r_bytes: np.random.Generator) -> None:
+    """Each write's new bytes: an update's one field (uniform over the
+    fields) and its `fieldlength` bytes, an insert's whole record."""
+    n, width, length = len(ops), shape.width, shape.fieldlength
+    one = ops.kind == KINDS.index(UPDATE)
+    whole = ops.kind == KINDS.index(INSERT)
+    field = np.full(n, -1, np.int64)
+    field[one] = r_field.integers(0, shape.fieldcount, size=int(one.sum()))
+    data = np.zeros((n, width), np.uint8)
+    rows = np.flatnonzero(one)
+    cols = field[rows, None] * length + np.arange(length)
+    data[rows[:, None], cols] = r_bytes.integers(0, 256, size=(len(rows), length),
+                                                 dtype=np.uint8)
+    data[whole] = r_bytes.integers(0, 256, size=(int(whole.sum()), width), dtype=np.uint8)
+    ops.field, ops.data, ops.shape = field, data, shape
 
 
 def inserts_needed(traffic: Dict, seconds: float) -> int:
